@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import ivf as ivf_lib
 from repro.core import probes as probes_lib
 from repro.core import summaries as summaries_lib
@@ -307,7 +306,8 @@ class ShardedSearchConfig:
     # "xla_map" (dry-run exec variant), "xla_vmap" (dry-run cost variant).
     # Tiled, probe-deduplicated scans with streaming top-k: "pallas_tiled"
     # (TPU), "pallas_tiled_interpret" (CPU tests), "xla_tiled" (fast CPU).
-    backend: str = "pallas_interpret"
+    # None: "pallas_tiled" on a TPU, "pallas_interpret" elsewhere.
+    backend: Optional[str] = None
     quantized: bool = False  # SQ8 lists (see ivf.quantize_index)
     # Filter-aware probe pruning from the index's resident cluster attribute
     # summaries (core/summaries.py), replicated like the centroids: "auto"
@@ -342,6 +342,11 @@ def make_sharded_search(
             f"build time (storage.reshard handles this)."
         )
     k_local = n_clusters // n_shards
+    on_tpu = jax.default_backend() == "tpu"
+    if cfg.backend is None:
+        cfg = dataclasses.replace(
+            cfg, backend="pallas_tiled" if on_tpu else "pallas_interpret"
+        )
     p_cap = probe_capacity(q_total, cfg.n_probes, n_shards, cfg.p_cap_slack)
     merge_axes = tuple(reversed(axes))  # model → data → pod
     needs_norms = metric == "l2"
@@ -376,7 +381,7 @@ def make_sharded_search(
         return topk_lib.topk_tree_merge(vals, out_ids, cfg.k, merge_axes)
 
     quantized = cfg.quantized
-    sharded_local = compat.shard_map(
+    sharded_local = jax.shard_map(
         _local,
         mesh=mesh,
         in_specs=(shard_spec, shard_spec, shard_spec, shard_spec, shard_spec,
@@ -385,7 +390,7 @@ def make_sharded_search(
         out_specs=(repl, repl),
         # pallas_call's out_shape carries no varying-mesh-axes annotation;
         # VMA/replication checking cannot see through it, so it is disabled.
-        check=False,
+        check_vma=False,
     )
 
     def search_fn(index: IVFFlatIndex, queries: Array, fspec: FilterSpec,
@@ -398,7 +403,7 @@ def make_sharded_search(
             q_block=min(cfg.q_block, queries.shape[0]),
             k_block=min(cfg.k_block, n_clusters),
             metric=metric, use_kernel=cfg.use_centroid_kernel,
-            interpret=cfg.backend not in ("pallas", "pallas_tiled"),
+            interpret=not on_tpu,
         )
         # ---- filter-aware prune mask (replicated, like the plan stage) ----
         from repro.core.engine import resolve_prune

@@ -23,6 +23,7 @@ and a sharded billion-vector index under pjit.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -110,14 +111,20 @@ def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+@functools.partial(jax.jit, static_argnames=("n_clusters", "vpad"))
 def scatter_to_lists(
-    values: Array, assignments: Array, n_clusters: int, vpad: int
-) -> Tuple[Array, Array, Array]:
-    """Sorts rows by cluster and scatters into padded lists.
+    values: Tuple[Array, ...], assignments: Array, n_clusters: int,
+    vpad: int,
+) -> Tuple[Tuple[Array, ...], Array, Array]:
+    """Sorts rows by cluster and scatters each array into padded lists.
 
-    Returns (lists [K, vpad, ...], slot_of_row [N], n_dropped scalar).
-    Rows beyond a list's capacity are dropped (mode="drop"), mirroring MoE
-    capacity semantics; callers size vpad so drops are zero in practice.
+    ``values`` is a tuple of ``[N, ...]`` arrays in the same row order
+    (vectors, attributes, ids).  Returns (tuple of lists ``[K, vpad, ...]``,
+    slot_of_row [N], n_dropped scalar).  Rows beyond a list's capacity are
+    dropped (mode="drop"), mirroring MoE capacity semantics; callers size
+    vpad so drops are zero in practice.  One jitted program shares the sort
+    between the arrays and fuses each sorted copy into its scatter: run
+    eagerly, a multi-GB build holds three copies of the rows.
     """
     n = assignments.shape[0]
     order = jnp.argsort(assignments)  # stable
@@ -125,10 +132,10 @@ def scatter_to_lists(
     # position-within-cluster for sorted rows: arange - start_of_cluster
     starts = jnp.searchsorted(a_sorted, jnp.arange(n_clusters), side="left")
     pos = jnp.arange(n) - jnp.take(starts, a_sorted)
-    out_shape = (n_clusters, vpad) + values.shape[1:]
-    lists = jnp.zeros(out_shape, values.dtype)
-    lists = lists.at[a_sorted, pos].set(
-        jnp.take(values, order, axis=0), mode="drop"
+    lists = tuple(
+        jnp.zeros((n_clusters, vpad) + v.shape[1:], v.dtype)
+        .at[a_sorted, pos].set(jnp.take(v, order, axis=0), mode="drop")
+        for v in values
     )
     dropped = jnp.sum((pos >= vpad).astype(jnp.int32))
     # slot index of each ORIGINAL row (for id→location bookkeeping)
@@ -167,12 +174,10 @@ def build_from_assignments(
     if ids is None:
         ids = jnp.arange(n, dtype=jnp.int32)
 
-    vec_lists, _, dropped = scatter_to_lists(core, assignments, k, vpad)
-    attr_lists, _, _ = scatter_to_lists(attrs, assignments, k, vpad)
-    id_init = jnp.full((k, vpad), -1, jnp.int32)
-    id_lists, _, _ = scatter_to_lists(
-        ids.astype(jnp.int32), assignments, k, vpad
+    (vec_lists, attr_lists, id_lists), _, dropped = scatter_to_lists(
+        (core, attrs, ids.astype(jnp.int32)), assignments, k, vpad
     )
+    id_init = jnp.full((k, vpad), -1, jnp.int32)
     # scatter_to_lists zero-fills; repaint empty slots with -1 sentinel.
     slot = jnp.arange(vpad)[None, :]
     live = slot < jnp.minimum(counts, vpad)[:, None]
@@ -234,10 +239,14 @@ def build_ivf(
     """
     n = core.shape[0]
     k = n_clusters or default_n_clusters(n)
+    # The k-means and assignment kernels widen rows to f32 themselves, a
+    # block at a time; an up-front f32 copy of all N rows would double the
+    # build's device footprint.
+    core = jnp.asarray(core)
     if kmeans_mode == "minibatch":
         state = kmeans_lib.minibatch_kmeans(
             key,
-            core.astype(jnp.float32),
+            core,
             n_clusters=k,
             n_steps=kmeans_steps,
             batch_size=min(kmeans_batch, n),
@@ -245,15 +254,13 @@ def build_ivf(
         centroids = state.centroids
     elif kmeans_mode == "lloyd":
         state, _ = kmeans_lib.kmeans_lloyd(
-            key, core.astype(jnp.float32), n_clusters=k, n_iters=kmeans_steps
+            key, core, n_clusters=k, n_iters=kmeans_steps
         )
         centroids = state.centroids
     else:
         raise ValueError(f"unknown kmeans_mode {kmeans_mode!r}")
 
-    assignments = kmeans_lib.assign(
-        core.astype(jnp.float32), centroids, chunk=assign_chunk
-    )
+    assignments = kmeans_lib.assign(core, centroids, chunk=assign_chunk)
     index, stats = build_from_assignments(
         spec, centroids, core, attrs, assignments, vpad=vpad, ids=ids,
         with_summaries=with_summaries, summary_bins=summary_bins,
@@ -269,11 +276,14 @@ def validity_mask(index: IVFFlatIndex) -> Array:
     )
 
 
+@jax.jit
 def quantize_index(index: IVFFlatIndex) -> IVFFlatIndex:
     """SQ8: per-vector symmetric int8 quantization of the flat lists.
 
     score(q, v̂) = (q · v_int8) · scale reproduces q·v to ~0.4% relative
-    error on unit-norm data; centroids stay f32 (probing is exact).
+    error on unit-norm data; centroids stay f32 (probing is exact).  Jitted
+    so the f32 widening stays inside one fused pass instead of a full f32
+    copy of the lists.
     """
     if index.quantized:
         return index

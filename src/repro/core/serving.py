@@ -19,6 +19,7 @@ pattern for the pod runtime:
 from __future__ import annotations
 
 import dataclasses
+import logging
 import queue
 import threading
 import time
@@ -31,6 +32,7 @@ import numpy as np
 from repro.core.filters import FilterSpec, match_all
 
 Array = jax.Array
+logger = logging.getLogger(__name__)
 
 
 def make_fused_search_fn(index, *, k: int, n_probes: int, q_block: int = 64,
@@ -254,12 +256,30 @@ def make_fused_search_fn(index, *, k: int, n_probes: int, q_block: int = 64,
     return search_fn
 
 
+class Reply(queue.Queue):
+    """One request's delivery channel (size 1).
+
+    ``get`` returns the :class:`Response`, or raises the exception that the
+    request's batch failed with, so a failed search reaches its caller
+    instead of leaving it blocked until its timeout.
+    """
+
+    def __init__(self):
+        super().__init__(maxsize=1)
+
+    def get(self, block: bool = True, timeout: Optional[float] = None):
+        item = super().get(block, timeout)
+        if isinstance(item, BaseException):
+            raise item
+        return item
+
+
 @dataclasses.dataclass
 class Request:
     query: np.ndarray  # [D]
     lo: np.ndarray  # [F, M] int16
     hi: np.ndarray  # [F, M]
-    future: "queue.Queue"  # delivery channel (size 1)
+    future: Reply  # delivery channel (size 1)
     t_enqueue: float = 0.0
 
 
@@ -329,17 +349,18 @@ class SearchServer:
         self._refresh = threading.Event()
         self._worker: Optional[threading.Thread] = None
         self.stats = dict(batches=0, requests=0, degraded_batches=0,
-                          total_latency_s=0.0, refreshes=0)
+                          failed_batches=0, total_latency_s=0.0,
+                          refreshes=0)
 
     # ---- client side ----
     def submit(self, query: np.ndarray, fspec_row: Optional[Tuple] = None
-               ) -> "queue.Queue":
+               ) -> Reply:
         if fspec_row is None:
             wild = match_all(1, self.n_attrs, self.n_terms)
             lo, hi = np.asarray(wild.lo[0]), np.asarray(wild.hi[0])
         else:
             lo, hi = fspec_row
-        fut: "queue.Queue" = queue.Queue(maxsize=1)
+        fut = Reply()
         self._q.put(Request(np.asarray(query), np.asarray(lo),
                             np.asarray(hi), fut, time.monotonic()))
         return fut
@@ -419,7 +440,15 @@ class SearchServer:
             batch = self._drain()
             if not batch:
                 continue
-            self._serve(batch)
+            try:
+                self._serve(batch)
+            except Exception as e:  # the loop must outlive a failed batch
+                logger.exception("search batch of %d requests failed",
+                                 len(batch))
+                self.stats["failed_batches"] += 1
+                for r in batch:
+                    if r.future.empty():
+                        r.future.put(e)
 
     def _serve(self, batch: List[Request]):
         b = len(batch)

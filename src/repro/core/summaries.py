@@ -289,9 +289,15 @@ class ClusterBounds:
 
 
 @jax.jit
-def _bounds_rows(x32: Array, live: Array, centroids: Array,
+def _bounds_rows(vectors: Array, scales: Optional[Array], live: Array,
+                 centroids: Array,
                  norms: Optional[Array]) -> Tuple[Array, Array]:
-    """(radius, slack) over the live rows of ``x32 [K, Vpad, D]`` f32."""
+    """(radius, slack) over the live rows of ``vectors [K, Vpad, D]``.
+
+    The rows are widened to f32 inside the jit, so the widening fuses into
+    the reductions instead of materializing an f32 copy of the lists.
+    """
+    x32 = _stored_f32(vectors, scales)
     diff = x32 - centroids.astype(jnp.float32)[:, None, :]
     d2 = jnp.sum(diff * diff, axis=-1)  # [K, Vpad]
     # d2 >= 0, so masking dead rows to 0 keeps the max sound and gives an
@@ -327,7 +333,8 @@ def build_bounds(centroids: Array, vectors: Array, ids: Array,
     """
     live = jnp.asarray(ids) >= 0
     radius, slack = _bounds_rows(
-        _stored_f32(vectors, scales), live, jnp.asarray(centroids),
+        jnp.asarray(vectors), None if scales is None else jnp.asarray(scales),
+        live, jnp.asarray(centroids),
         None if norms is None else jnp.asarray(norms),
     )
     return ClusterBounds(radius=radius, slack=slack)
@@ -340,7 +347,8 @@ def rebuild_cluster_bounds(bounds: ClusterBounds, centroid_row: Array,
                            cluster) -> ClusterBounds:
     """Recomputes one cluster's bound row exactly (compaction, rebuilds)."""
     radius, slack = _bounds_rows(
-        _stored_f32(vectors_row, scales_row)[None],
+        jnp.asarray(vectors_row)[None],
+        None if scales_row is None else jnp.asarray(scales_row)[None],
         (jnp.asarray(ids_row) >= 0)[None],
         jnp.asarray(centroid_row)[None],
         None if norms_row is None else jnp.asarray(norms_row)[None],

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -3.0e38
 
 
@@ -120,7 +118,7 @@ def centroid_topk(
             pltpu.VMEM((q_block, t), jnp.float32),
             pltpu.VMEM((q_block, t), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

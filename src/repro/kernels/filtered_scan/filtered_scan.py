@@ -57,8 +57,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -3.0e38
 
 
@@ -249,7 +247,7 @@ def filtered_scan(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((p, vpad), jnp.float32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -267,41 +265,64 @@ def _fold_topk(run_v, run_i, scores, ids_blk, k):
 
     Branch-free static-k max-extraction (the centroid_topk idiom) — no
     reliance on sort/top_k lowering inside the kernel.  Ties resolve to the
-    earliest candidate position, which (running set first, then the block in
-    slot order) reproduces ``lax.top_k``'s first-index tie order over the
-    flat list.
+    earliest candidate position, running set first and then the block in
+    slot order, which reproduces ``lax.top_k``'s first-index tie order over
+    the flat list.
+
+    Shapes: ``run_v/run_i [QB, k]``, ``scores [QB, VB]``, ``ids_blk [1, VB]``.
+    The two candidate sets are never concatenated (a lane-unaligned concat
+    at k=100), every reduction keeps its lane axis (``[QB, 1]`` columns), and
+    the picked id is selected through the one-hot position mask rather than
+    an in-kernel gather, so the body lowers to plain VPU/XLU ops in Mosaic.
     """
-    cand_v = jnp.concatenate([run_v, scores], axis=1)  # [QB, k+VB]
-    cand_i = jnp.concatenate([run_i, ids_blk], axis=1)
-    new_v = []
-    new_i = []
-    for _ in range(k):
-        m = jnp.max(cand_v, axis=1)  # [QB]
-        am = jnp.argmax(cand_v, axis=1)
-        picked = jnp.take_along_axis(cand_i, am[:, None], axis=1)[:, 0]
-        new_v.append(m)
-        new_i.append(jnp.where(m > NEG_INF / 2, picked, -1))
-        hit = (
-            jax.lax.broadcasted_iota(jnp.int32, cand_v.shape, 1)
-            == am[:, None]
-        )
-        cand_v = jnp.where(hit, NEG_INF, cand_v)
-    return jnp.stack(new_v, axis=1), jnp.stack(new_i, axis=1)
+    big = jnp.int32(2**30)
+    col_k = jax.lax.broadcasted_iota(jnp.int32, run_v.shape, 1)
+    col_b = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+
+    def extract(j, carry):
+        run_v, blk_v, out_v, out_i = carry
+        m = jnp.maximum(
+            jnp.max(run_v, axis=1, keepdims=True),
+            jnp.max(blk_v, axis=1, keepdims=True),
+        )  # [QB, 1]
+        pos_r = jnp.min(jnp.where(run_v == m, col_k, big), axis=1,
+                        keepdims=True)
+        pos_b = jnp.min(jnp.where(blk_v == m, col_b, big), axis=1,
+                        keepdims=True)
+        in_run = pos_r < big  # earliest position: the running set wins ties
+        hit_r = jnp.logical_and(col_k == pos_r, in_run)
+        hit_b = jnp.logical_and(col_b == pos_b, jnp.logical_not(in_run))
+        picked = (jnp.sum(jnp.where(hit_r, run_i, 0), axis=1, keepdims=True)
+                  + jnp.sum(jnp.where(hit_b, ids_blk, 0), axis=1,
+                            keepdims=True))
+        out_v = jnp.where(col_k == j, m, out_v)
+        out_i = jnp.where(col_k == j, jnp.where(m > NEG_INF / 2, picked, -1),
+                          out_i)
+        return (jnp.where(hit_r, NEG_INF, run_v),
+                jnp.where(hit_b, NEG_INF, blk_v), out_v, out_i)
+
+    _, _, out_v, out_i = jax.lax.fori_loop(
+        0, k, extract, (run_v, scores, run_v, run_i)
+    )
+    return out_v, out_i
 
 
 def _tiled_kernel(
     slot_cluster_ref,  # scalar prefetch (drives index_maps)
     slot_tile_ref,
     q_ref,  # [QB, D]
-    lo_ref,  # [QB, F, M]
-    hi_ref,  # [QB, F, M]
+    lo_ref,  # [QB, F·M] int32
+    hi_ref,  # [QB, F·M] int32
     v_ref,  # [1, VB, D]
-    a_ref,  # [1, VB, M]
-    id_ref,  # [1, VB]
-    *rest,  # ([aux_ref [1, VB]], ov_ref [1,QB,k], oi_ref [1,QB,k], op_ref [1,QB])
+    a_ref,  # [1, M, VB] int16
+    id_ref,  # [1, 1, VB]
+    *rest,  # ([aux_ref [1,1,VB]], ov_ref [1,QB,k], oi_ref [1,QB,k],
+    #          op_ref [1,QB,1])
     k: int,
     metric: str,
     quantized: bool,
+    n_terms: int,
+    n_attrs: int,
 ):
     del slot_cluster_ref, slot_tile_ref
     if metric == "l2" or quantized:
@@ -326,29 +347,34 @@ def _tiled_kernel(
         q, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # [QB, VB]
     if quantized:
-        scores = scores * aux_ref[0][None, :]  # SQ8 dequant on the VPU
+        scores = scores * aux_ref[0]  # SQ8 dequant on the VPU ([1, VB] row)
     if metric == "l2":
-        scores = 2.0 * scores - aux_ref[0][None, :]  # ‖q‖² added by wrapper
+        scores = 2.0 * scores - aux_ref[0]  # ‖q‖² added by the wrapper
 
-    a = a_ref[0].astype(jnp.int32)  # [VB, M]
-    lo = lo_ref[...].astype(jnp.int32)  # [QB, F, M]
-    hi = hi_ref[...].astype(jnp.int32)
-    fmask = None  # per-query DNF interval test, [QB, VB] in VREGs
-    for fi in range(lo.shape[1]):
-        term = jnp.all(
-            jnp.logical_and(
-                a[None] >= lo[:, fi][:, None], a[None] <= hi[:, fi][:, None]
-            ),
-            axis=-1,
-        )
+    # Per-query DNF interval test, built one attribute at a time as
+    # [1, VB] row vs [QB, 1] column compares: the rows sit on the lane axis,
+    # so no [QB, VB, M] intermediate with M on the lanes ever exists.
+    a = a_ref[0].astype(jnp.int32)  # [M, VB]
+    lo = lo_ref[...]  # [QB, F·M]
+    hi = hi_ref[...]
+    fmask = None  # None ⇔ every row passes (no attributes to test)
+    for fi in range(n_terms if n_attrs else 0):
+        term = None
+        for mi in range(n_attrs):
+            c = fi * n_attrs + mi
+            row = a[mi:mi + 1, :]
+            inside = jnp.logical_and(row >= lo[:, c:c + 1],
+                                     row <= hi[:, c:c + 1])
+            term = inside if term is None else jnp.logical_and(term, inside)
         fmask = term if fmask is None else jnp.logical_or(fmask, term)
-    live = id_ref[0] >= 0  # [VB]
-    mask = jnp.logical_and(fmask, live[None, :])
+    live = id_ref[0] >= 0  # [1, VB]
+    mask = live if fmask is None else jnp.logical_and(fmask, live)
+    mask = jnp.broadcast_to(mask, scores.shape)
     scores = jnp.where(mask, scores, NEG_INF)
-    op_ref[0] = op_ref[0] + jnp.sum(mask.astype(jnp.int32), axis=1)
+    op_ref[0] = op_ref[0] + jnp.sum(mask.astype(jnp.int32), axis=1,
+                                    keepdims=True)
 
-    ids_blk = jnp.broadcast_to(id_ref[0][None, :], scores.shape)
-    new_v, new_i = _fold_topk(ov_ref[0], oi_ref[0], scores, ids_blk, k)
+    new_v, new_i = _fold_topk(ov_ref[0], oi_ref[0], scores, id_ref[0], k)
     ov_ref[0] = new_v
     oi_ref[0] = new_i
 
@@ -423,54 +449,62 @@ def filtered_scan_tiled(
         del vi, sc
         return (st[si], 0)
 
-    def im_bounds(si, vi, sc, st):
-        del vi, sc
-        return (st[si], 0, 0)
-
     def im_vec(si, vi, sc, st):
         del st
         return (sc[si], vi, 0)
 
     def im_rows(si, vi, sc, st):
         del st
-        return (sc[si], vi)
+        return (sc[si], 0, vi)
 
-    def im_out3(si, vi, sc, st):
+    def im_out(si, vi, sc, st):
         del vi, sc, st
         return (si, 0, 0)
 
-    def im_out2(si, vi, sc, st):
-        del vi, sc, st
-        return (si, 0)
+    # Mosaic tiles the last two block dims by (8, 128) unless a dim spans
+    # the whole array, so every per-row operand gets a unit middle axis
+    # ([K, 1, Vpad], block (1, 1, VB)) and npass comes back as [S, QB, 1].
+    # Attributes go in as [K, M, Vpad]: with M=10 as the minor dim the TPU
+    # layout pads it to 128 lanes, a 12.8× relayout copy of the whole
+    # attribute table per call.  The DNF bounds are flattened to
+    # [Qpad, F·M] int32 query rows.
+    def rows(x):
+        return x.reshape(x.shape[0], 1, x.shape[1])
 
     in_specs = [
         pl.BlockSpec((q_block, d), im_query),
-        pl.BlockSpec((q_block, f, m), im_bounds),
-        pl.BlockSpec((q_block, f, m), im_bounds),
+        pl.BlockSpec((q_block, f * m), im_query),
+        pl.BlockSpec((q_block, f * m), im_query),
         pl.BlockSpec((1, v_block, d), im_vec),
-        pl.BlockSpec((1, v_block, m), im_vec),
-        pl.BlockSpec((1, v_block), im_rows),
+        pl.BlockSpec((1, m, v_block), im_rows),
+        pl.BlockSpec((1, 1, v_block), im_rows),
     ]
-    operands = [queries, lo, hi, vectors, attrs, ids]
+    operands = [
+        queries,
+        lo.reshape(qpad, f * m).astype(jnp.int32),
+        hi.reshape(qpad, f * m).astype(jnp.int32),
+        vectors, jnp.swapaxes(attrs, 1, 2), rows(ids),
+    ]
     quantized = scales is not None
     if metric == "l2":
-        in_specs.append(pl.BlockSpec((1, v_block), im_rows))
-        operands.append(norms)
+        in_specs.append(pl.BlockSpec((1, 1, v_block), im_rows))
+        operands.append(rows(norms))
     elif quantized:
-        in_specs.append(pl.BlockSpec((1, v_block), im_rows))
-        operands.append(scales)
+        in_specs.append(pl.BlockSpec((1, 1, v_block), im_rows))
+        operands.append(rows(scales))
 
     kernel = functools.partial(
-        _tiled_kernel, k=k, metric=metric, quantized=quantized
+        _tiled_kernel, k=k, metric=metric, quantized=quantized, n_terms=f,
+        n_attrs=m,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, q_block, k), im_out3),
-            pl.BlockSpec((1, q_block, k), im_out3),
-            pl.BlockSpec((1, q_block), im_out2),
+            pl.BlockSpec((1, q_block, k), im_out),
+            pl.BlockSpec((1, q_block, k), im_out),
+            pl.BlockSpec((1, q_block, 1), im_out),
         ],
     )
     vals, out_ids, npass = pl.pallas_call(
@@ -479,14 +513,14 @@ def filtered_scan_tiled(
         out_shape=[
             jax.ShapeDtypeStruct((s, q_block, k), jnp.float32),
             jax.ShapeDtypeStruct((s, q_block, k), jnp.int32),
-            jax.ShapeDtypeStruct((s, q_block), jnp.int32),
+            jax.ShapeDtypeStruct((s, q_block, 1), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(slot_cluster.astype(jnp.int32), slot_tile.astype(jnp.int32), *operands)
-    return vals, out_ids, npass
+    return vals, out_ids, npass[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -19,7 +19,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.configs import deepseek_v3_671b  # noqa: E402
 from repro.launch.mesh import make_production_mesh, dp_axes  # noqa: E402
 from repro.models.transformer import init_params, lm_loss, param_pspecs  # noqa: E402
-from repro import compat  # noqa: E402
 from repro.train.optimizer import (  # noqa: E402
     OptimizerConfig, adafactor_state_pspecs, clip_by_global_norm,
     make_optimizer,
@@ -73,7 +72,7 @@ def main():
 
     t1 = time.time()
     jitted = jax.jit(train_step, donate_argnums=(0, 1))
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params_abs, opt_abs, tok, tok)
         t2 = time.time()
         print(f"lower: {t2-t1:.1f}s")

@@ -31,7 +31,6 @@ import traceback  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro import compat
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -110,7 +109,7 @@ def _compile_cell(cell, mesh, trip_counts):
     from repro.launch.mesh import n_chips
 
     t0 = time.time()
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, donate_argnums=cell.donate)
         lowered = jitted.lower(*cell.args)
         t_lower = time.time()

@@ -40,6 +40,9 @@ def _sample_queries(disk_index, max_clusters: int = 4) -> np.ndarray:
 
 
 def main():
+    from repro.launch import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=100_000)
     ap.add_argument("--dim", type=int, default=64)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 
 Array = jax.Array
 
@@ -113,10 +112,10 @@ def sharded_embedding_bag(
             out = out / jnp.maximum(n, 1.0)
         return out
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", None), P(dp_axes, *([None] * (ids.ndim - 1)))),
         out_specs=P(dp_axes, *([None] * (ids.ndim - 2)), None),
-        check=False,
+        check_vma=False,
     )(table, ids)

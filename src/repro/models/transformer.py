@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.models import moe as moe_lib
 from repro.models.config import TransformerConfig
 from repro.models.layers import (
@@ -362,13 +361,13 @@ def _moe_ffn(x, moe_params, cfg: TransformerConfig,
         dp = P(dp_eff if dp_eff else None, None, None)
         out_spec = (P(dp_eff if dp_eff else None, "model", None)
                     if use_scatter else dp)
-        out, aux, dropped = compat.shard_map(
+        out, aux, dropped = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(dp, P(ep_axes, fsdp, None), P(ep_axes, fsdp, None),
                       P(ep_axes, None, fsdp), P(None, None), P(None)),
             out_specs=(out_spec, P(), P()),
-            check=False,
+            check_vma=False,
         )(x, moe_params["wg"], moe_params["wu"], moe_params["wd"],
           moe_params["router"], moe_params["router_bias"])
     if mcfg.n_shared:

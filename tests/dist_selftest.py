@@ -19,8 +19,8 @@ os.environ["XLA_FLAGS"] = (
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import (  # noqa: E402
     FilterBuilder,
     HybridSpec,
@@ -51,7 +51,9 @@ def main():
     )
     assert stats.n_dropped == 0
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(AxisType.Auto, AxisType.Auto)
+    )
     q = 16
     cfg = ShardedSearchConfig(k=20, n_probes=4, v_block=128)
     search_fn, shardings, info = make_sharded_search(
@@ -218,7 +220,7 @@ def main():
     outs = {}
     for combine in ("psum", "scatter"):
         cfgc = dc.replace(cfg0, moe_combine=combine)
-        with compat.use_mesh(mesh):
+        with jax.set_mesh(mesh):
             h, _ = jax.jit(
                 lambda p, t: forward(p, cfgc, t, mesh=mesh,
                                      dp_axes=("data",))

@@ -1,5 +1,7 @@
 """Persistence round-trips, elastic resharding, and the serving loop."""
 
+import time
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -107,3 +109,36 @@ def test_serving_loop_end_to_end(built):
         assert not r.degraded
     assert server.stats["requests"] == 20
     assert server.stats["batches"] >= 3  # micro-batching actually batched
+
+
+def test_serving_loop_failed_batch_reaches_caller():
+    """An exception from search_fn is raised at every waiting caller (not a
+    queue.Empty at the timeout), and the loop keeps serving later batches."""
+    failing = [True]
+
+    def search_fn(queries, fspec, shard_ok):
+        del fspec, shard_ok
+        if failing[0]:
+            raise RuntimeError("scan failed on the device")
+        q = queries.shape[0]
+        return np.zeros((q, 2), np.float32), np.zeros((q, 2), np.int32)
+
+    server = SearchServer(
+        search_fn, batch_size=4, dim=3, n_attrs=2, n_terms=1, n_shards=1,
+        max_wait_s=0.01,
+    )
+    server.start()
+    try:
+        futs = [server.submit(np.zeros(3, np.float32)) for _ in range(4)]
+        t0 = time.monotonic()
+        for f in futs:
+            with pytest.raises(RuntimeError, match="scan failed"):
+                f.get(timeout=30)
+        assert time.monotonic() - t0 < 10
+        failing[0] = False
+        again = server.search_blocking(np.zeros(3, np.float32), timeout=30)
+    finally:
+        server.stop()
+    assert again.ids.shape == (2,)
+    assert server.stats["failed_batches"] >= 1
+    assert not server._worker.is_alive()
