@@ -63,6 +63,9 @@ from repro.core import summaries as summaries_lib
 from repro.core import topk as topk_lib
 from repro.core.filters import FilterSpec
 from repro.core.ivf import round_up
+from repro.core.obs import (StageHistogram, _flatten_metrics,
+                            render_prometheus, render_stage_histograms,
+                            span)
 from repro.core.search import SearchResult, centroid_scores
 
 Array = jax.Array
@@ -126,6 +129,7 @@ def tiled_scan_xla(
     static_argnames=("metric", "n_probes", "q_block", "u_cap", "cast_dtype",
                      "t_max"),
 )
+@jax.named_scope("plan")
 def plan_fused_tiled(
     centroids: Array,
     counts: Array,
@@ -297,59 +301,65 @@ def _scan_merge_tiled(
     from repro.kernels.filtered_scan.filtered_scan import filtered_scan_tiled
 
     qpad = queries_pad.shape[0]
-    if backend in ("pallas", "pallas_interpret"):
-        svals, sids, snpass = filtered_scan_tiled(
-            slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
-            vectors, attrs, ids, norms, scales,
-            metric=metric, k=k, q_block=q_block, v_block=v_block,
-            interpret=backend == "pallas_interpret",
-        )
-    elif backend == "xla":
-        svals, sids, snpass = tiled_scan_xla(
-            slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
-            vectors, attrs, ids, norms, scales,
-            metric=metric, k=k, q_block=q_block,
-        )
-    else:
-        raise ValueError(backend)
+    with jax.named_scope("scan"):
+        if backend in ("pallas", "pallas_interpret"):
+            svals, sids, snpass = filtered_scan_tiled(
+                slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
+                vectors, attrs, ids, norms, scales,
+                metric=metric, k=k, q_block=q_block, v_block=v_block,
+                interpret=backend == "pallas_interpret",
+            )
+        elif backend == "xla":
+            svals, sids, snpass = tiled_scan_xla(
+                slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
+                vectors, attrs, ids, norms, scales,
+                metric=metric, k=k, q_block=q_block,
+            )
+        else:
+            raise ValueError(backend)
 
-    # Per-probe candidate fragments, then the monoid merge across T probes.
-    # Probes that overflowed an undersized u_cap are dropped soundly (their
-    # fragments masked out), mirroring the distributed dispatch's P_cap.
-    row = jnp.arange(qpad, dtype=jnp.int32) % q_block  # [Qpad]
-    vals_qt = svals[slot_of_probe, row[:, None]]  # [Qpad, T, k]
-    ids_qt = sids[slot_of_probe, row[:, None]]
-    npass_qt = snpass[slot_of_probe, row[:, None]]  # [Qpad, T]
-    vals_qt = jnp.where(probe_ok[..., None], vals_qt, topk_lib.NEG_INF)
-    ids_qt = jnp.where(probe_ok[..., None], ids_qt, -1)
-    npass_qt = jnp.where(probe_ok, npass_qt, 0)
-    vals, out_ids = topk_lib.merge_topk_many(vals_qt, ids_qt, k, axis=1)
-    vals, out_ids = vals[:q], out_ids[:q]
+    with jax.named_scope("merge"):
+        # Per-probe candidate fragments, then the monoid merge across T
+        # probes.  Probes that overflowed an undersized u_cap are dropped
+        # soundly (their fragments masked out), mirroring the distributed
+        # dispatch's P_cap.
+        row = jnp.arange(qpad, dtype=jnp.int32) % q_block  # [Qpad]
+        vals_qt = svals[slot_of_probe, row[:, None]]  # [Qpad, T, k]
+        ids_qt = sids[slot_of_probe, row[:, None]]
+        npass_qt = snpass[slot_of_probe, row[:, None]]  # [Qpad, T]
+        vals_qt = jnp.where(probe_ok[..., None], vals_qt, topk_lib.NEG_INF)
+        ids_qt = jnp.where(probe_ok[..., None], ids_qt, -1)
+        npass_qt = jnp.where(probe_ok, npass_qt, 0)
+        vals, out_ids = topk_lib.merge_topk_many(vals_qt, ids_qt, k, axis=1)
+        vals, out_ids = vals[:q], out_ids[:q]
 
-    if metric == "l2":
-        q2 = jnp.sum(queries.astype(jnp.float32) ** 2, -1)  # [Q]
-        vals = jnp.where(
-            vals > topk_lib.NEG_INF / 2, vals - q2[:, None], vals
+        if metric == "l2":
+            q2 = jnp.sum(queries.astype(jnp.float32) ** 2, -1)  # [Q]
+            vals = jnp.where(
+                vals > topk_lib.NEG_INF / 2, vals - q2[:, None], vals
+            )
+
+        n_passed = jnp.sum(npass_qt[:q], axis=-1)
+        # Scan accounting through the slot tables: a probe's slot scans
+        # exactly its cluster, so live-rows-per-slot gathered by
+        # slot_of_probe equals the old per-cluster lookup — and works when
+        # only gathered rows exist.
+        # [K or S]
+        live_per_row = jnp.sum((ids >= 0).astype(jnp.int32), axis=-1)
+        live_per_slot = jnp.take(live_per_row, slot_cluster)  # [S_flat]
+        n_scanned = jnp.sum(
+            jnp.take(live_per_slot, slot_of_probe[:q])
+            * probe_ok[:q].astype(jnp.int32),
+            axis=-1,
         )
-
-    n_passed = jnp.sum(npass_qt[:q], axis=-1)
-    # Scan accounting through the slot tables: a probe's slot scans exactly
-    # its cluster, so live-rows-per-slot gathered by slot_of_probe equals the
-    # old per-cluster lookup — and works when only gathered rows exist.
-    live_per_row = jnp.sum((ids >= 0).astype(jnp.int32), axis=-1)  # [K or S]
-    live_per_slot = jnp.take(live_per_row, slot_cluster)  # [S_flat]
-    n_scanned = jnp.sum(
-        jnp.take(live_per_slot, slot_of_probe[:q])
-        * probe_ok[:q].astype(jnp.int32),
-        axis=-1,
-    )
-    return SearchResult(vals, out_ids, n_scanned, n_passed)
+        return SearchResult(vals, out_ids, n_scanned, n_passed)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("metric", "k", "q_block", "v_block", "backend"),
 )
+@jax.named_scope("scan")
 def _scan_slots(
     slot_cluster: Array,   # [S] rows into the operand arrays (one segment)
     queries_pad: Array,    # [QB, D] one tile's cast queries
@@ -395,6 +405,7 @@ def _scan_slots(
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "k", "q"))
+@jax.named_scope("merge")
 def _merge_tile_fragments(
     svals: Array,          # [S_pad, QB, k] per-slot fragments (filler where
     sids: Array,           #   a segment was never scanned)
@@ -710,131 +721,6 @@ class EngineStats:
         return max(0.0, 1.0 - self.io_wait_s / self.io_total_s)
 
 
-def _flatten_metrics(out: Dict[str, Any], prefix: str, obj: Any) -> None:
-    """Recursively flattens nested stats into ``prefix.key`` scalar entries
-    (dict values recurse; numbers/bools/strings pass through; anything else
-    is stringified so the scrape never chokes on a stray object)."""
-    if isinstance(obj, dict):
-        for key, val in obj.items():
-            _flatten_metrics(out, f"{prefix}.{key}", val)
-    elif isinstance(obj, (bool, int, float, str)) or obj is None:
-        out[prefix] = obj
-    elif isinstance(obj, (np.integer, np.floating)):
-        out[prefix] = obj.item()
-    else:
-        out[prefix] = str(obj)
-
-
-# Metric leaf names that are monotonically increasing counts — rendered as
-# Prometheus counters; every other numeric metric is a gauge.
-_PROM_COUNTERS = frozenset((
-    "batches", "pipelined_batches", "tiles_scanned", "scan_compilations",
-    "blocks_fetched", "blocks_reused", "degraded_batches", "delta_folds",
-    "delta_skips", "hits", "misses", "puts", "evictions", "invalidations",
-    "prefetched", "errors", "stalled_waits", "failovers",
-    "redirected_blocks", "fallback_blocks", "stale_answers", "retries",
-    "deadline_misses", "device_hits", "tile_hits", "tile_puts", "l1_hits",
-    "l1_misses", "l1_invalidations", "remote_blocks", "blocks_served",
-    "adds", "tombstoned", "commits", "scan_compile_count",
-    "probes_terminated", "term_segments_skipped",
-    "partition_hits", "partition_fallbacks", "partition_rows_scanned",
-    "flat_rows_scanned", "delta_interval_skips", "fetches_skipped",
-))
-
-
-def _prom_name(key: str) -> str:
-    out = "".join(c if c.isalnum() or c == "_" else "_" for c in key)
-    return out if not out[:1].isdigit() else f"_{out}"
-
-
-def render_prometheus(metrics: Dict[str, Any],
-                      prefix: str = "repro") -> str:
-    """Flat dotted-key metrics → Prometheus text exposition format.
-
-    Dots become underscores (``engine.blocks_fetched`` →
-    ``repro_engine_blocks_fetched``); booleans render as 0/1 gauges;
-    strings become an info-style labeled sample
-    (``repro_engine_backend{value="xla"} 1``); None is skipped.  Leaf
-    names in :data:`_PROM_COUNTERS` are typed ``counter``, the rest
-    ``gauge``.
-    """
-    lines: List[str] = []
-    for key in sorted(metrics):
-        val = metrics[key]
-        if val is None:
-            continue
-        name = _prom_name(f"{prefix}.{key}")
-        leaf = key.rsplit(".", 1)[-1]
-        kind = "counter" if leaf in _PROM_COUNTERS else "gauge"
-        if isinstance(val, bool):
-            lines.append(f"# TYPE {name} gauge")
-            lines.append(f"{name} {int(val)}")
-        elif isinstance(val, (int, float)):
-            lines.append(f"# TYPE {name} {kind}")
-            lines.append(f"{name} {val}")
-        else:
-            label = str(val).replace("\\", "\\\\").replace('"', '\\"')
-            lines.append(f"# TYPE {name} gauge")
-            lines.append(f'{name}{{value="{label}"}} 1')
-    return "\n".join(lines) + "\n"
-
-
-# Fixed latency bucket upper bounds (seconds) for the per-stage histograms.
-# Chosen to straddle the measured stage costs from sub-ms RAM-resident plans
-# up to multi-second cold disk fetches; fixed so scrapes from different
-# processes aggregate.
-_LAT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
-                0.25, 0.5, 1.0, 2.5)
-
-
-class StageHistogram:
-    """Fixed-bucket latency histogram, Prometheus-renderable.
-
-    Buckets are cumulative at render time (classic ``le`` semantics, with
-    the implicit ``+Inf`` bucket equal to the total count); observation is
-    O(#buckets) with no allocation, cheap enough for per-tile scan timing.
-    """
-
-    __slots__ = ("counts", "total", "sum")
-
-    def __init__(self):
-        self.counts = [0] * len(_LAT_BUCKETS)
-        self.total = 0
-        self.sum = 0.0
-
-    def observe(self, seconds: float):
-        self.total += 1
-        self.sum += seconds
-        for i, edge in enumerate(_LAT_BUCKETS):
-            if seconds <= edge:
-                self.counts[i] += 1
-                break
-
-    def render(self, name: str, labels: str) -> List[str]:
-        lines = []
-        cum = 0
-        for edge, n in zip(_LAT_BUCKETS, self.counts):
-            cum += n
-            lines.append(f'{name}_bucket{{{labels},le="{edge}"}} {cum}')
-        lines.append(f'{name}_bucket{{{labels},le="+Inf"}} {self.total}')
-        lines.append(f"{name}_sum{{{labels}}} {self.sum}")
-        lines.append(f"{name}_count{{{labels}}} {self.total}")
-        return lines
-
-
-def render_stage_histograms(hists: Dict[str, StageHistogram],
-                            prefix: str = "repro") -> str:
-    """``{stage: histogram}`` → Prometheus exposition text (one metric
-    family, ``stage`` label per pipeline stage)."""
-    if not hists:
-        return ""
-    name = f"{prefix}_stage_latency_seconds"
-    lines = [f"# TYPE {name} histogram"]
-    for stage in sorted(hists):
-        lines.extend(hists[stage].render(name, f'stage="{stage}"'))
-    return "\n".join(lines) + "\n"
-
-
 # Process-wide registry of scan-stage signatures that have been dispatched;
 # mirrors the underlying jit cache (which is also process-wide), so a new key
 # here == a real XLA compilation.
@@ -1058,8 +944,9 @@ class SearchEngine:
         self.termination = termination
         self.epsilon = float(epsilon)
         self._bounds_cache = None  # (key, ClusterBounds) lazy-build memo
-        # per-stage fixed-bucket latency histograms (plan/fetch/scan/merge/
-        # delta_fold), appended to metrics_text() for the Prometheus scrape
+        # per-stage fixed-bucket latency histograms (plan and its parts,
+        # fetch, scan or scan_dispatch, merge_dispatch, delta_fold),
+        # appended to metrics_text() for the Prometheus scrape
         self._stage_hist: Dict[str, StageHistogram] = {}
         self.stats = EngineStats()
 
@@ -1068,6 +955,11 @@ class SearchEngine:
         if hist is None:
             hist = self._stage_hist[stage] = StageHistogram()
         hist.observe(seconds)
+
+    def _stage(self, stage: str) -> span:
+        """Span ``repro.engine.<stage>``, timed into the stage's histogram."""
+        return span(f"engine.{stage}",
+                    functools.partial(self._observe_stage, stage))
 
     def _delta_tier(self):
         if self._delta is not None:
@@ -1179,114 +1071,127 @@ class SearchEngine:
         ``adaptive_u_cap`` the tables are then shrunk to the smallest
         power-of-two bucket covering the observed per-tile unique counts.
         """
-        t0 = time.perf_counter()
-        index = self.index
-        q = queries.shape[0]
-        qb = min(self.q_block, round_up(q, 8))
-        summ = resolve_prune(index, self.prune)
-        # Partition routing: probing geometry (centroid top-k, summaries,
-        # widening, bounds) always runs over the BASE clusters — sub ids
-        # only enter via the plan-stage probe remap below, so an index with
-        # a catalog plans exactly like the flat index for unrouted queries.
-        cat = self._resolve_partitions()
-        # a RAM index with attached sub-partitions carries them inline in
-        # the per-cluster arrays — the planner slices to base width even
-        # with routing off, else the centroid top-k would probe the subs'
-        # duplicated centroids (not the flat plan)
-        cat_any = getattr(index, "partitions", None)
-        centroids = index.centroids
-        counts = index.counts
-        kc = index.n_clusters
-        if cat_any is not None:
-            kc = cat_any.n_base
-            centroids, counts, summ = self._base_views(cat_any, summ)
-        route, route_entry, members = self._route_partitions(cat, fspec)
-        # Capture an immutable view of the RAM delta segment for this batch,
-        # and plan with tombstone/append-adjusted cluster counts: a rebuilt
-        # index would see those counts, and centroid_scores masks empty
-        # clusters by count — parity requires the live planner to agree.
-        tier = self._delta_tier()
-        snap = tier.snapshot() if tier is not None else None
-        if snap is not None:
-            adj = tier.count_adjustment(kc)
-            if adj is not None:
-                counts = counts + jnp.asarray(adj)
-        t_max = self.t_max
-        if t_max == "auto":
-            # summary-driven widening: bucketed per batch from the expected
-            # passing mass, so a selective batch widens and an unfiltered
-            # one plans exactly like t_max=None (bit-identical)
-            t_max = resolve_auto_t_max(
-                summ, counts, fspec.lo, fspec.hi, self.n_probes, kc
-            )
-        if t_max is not None:
-            if t_max < self.n_probes:
-                raise ValueError(
-                    f"t_max={t_max} < n_probes={self.n_probes}"
+        with self._stage("plan"):
+            with self._stage("plan.prep"):
+                index = self.index
+                q = queries.shape[0]
+                qb = min(self.q_block, round_up(q, 8))
+                summ = resolve_prune(index, self.prune)
+                # Partition routing: probing geometry (centroid top-k,
+                # summaries, widening, bounds) always runs over the BASE
+                # clusters — sub ids only enter via the plan-stage probe remap
+                # below, so an index with a catalog plans exactly like the flat
+                # index for unrouted queries.
+                cat = self._resolve_partitions()
+                # a RAM index with attached sub-partitions carries them inline
+                # in the per-cluster arrays — the planner slices to base width
+                # even with routing off, else the centroid top-k would probe
+                # the subs' duplicated centroids (not the flat plan)
+                cat_any = getattr(index, "partitions", None)
+                centroids = index.centroids
+                counts = index.counts
+                kc = index.n_clusters
+                if cat_any is not None:
+                    kc = cat_any.n_base
+                    centroids, counts, summ = self._base_views(cat_any, summ)
+                route, route_entry, members = self._route_partitions(
+                    cat, fspec)
+                # Capture an immutable view of the RAM delta segment for this
+                # batch, and plan with tombstone/append-adjusted cluster
+                # counts: a rebuilt index would see those counts, and
+                # centroid_scores masks empty clusters by count — parity
+                # requires the live planner to agree.
+                tier = self._delta_tier()
+                snap = tier.snapshot() if tier is not None else None
+                if snap is not None:
+                    adj = tier.count_adjustment(kc)
+                    if adj is not None:
+                        counts = counts + jnp.asarray(adj)
+                t_max = self.t_max
+                if t_max == "auto":
+                    # summary-driven widening: bucketed per batch from the
+                    # expected passing mass, so a selective batch widens and an
+                    # unfiltered one plans exactly like t_max=None
+                    # (bit-identical)
+                    t_max = resolve_auto_t_max(
+                        summ, counts, fspec.lo, fspec.hi, self.n_probes, kc
+                    )
+                if t_max is not None:
+                    if t_max < self.n_probes:
+                        raise ValueError(
+                            f"t_max={t_max} < n_probes={self.n_probes}"
+                        )
+                    t_max = min(t_max, kc)
+                    if summ is None or t_max == self.n_probes:
+                        # widening is only meaningful with pruning
+                        t_max = None
+                width = self.n_probes if t_max is None else t_max
+                # remapped probes draw from base ∪ sub ids, so the per-tile
+                # unique count can exceed the base cluster count — provision
+                # for the full id space or the dedup's overflow drop would
+                # break parity
+                k_total = kc + (cat.n_subs if cat is not None else 0)
+                full_cap = min(qb * width, k_total)
+                cap = full_cap if self.u_cap is None else self.u_cap
+                cast_dtype = (
+                    np.dtype(np.float32) if index.quantized
+                    else np.dtype(index.store_dtype)
                 )
-            t_max = min(t_max, kc)
-            if summ is None or t_max == self.n_probes:
-                t_max = None  # widening is only meaningful with pruning
-        width = self.n_probes if t_max is None else t_max
-        # remapped probes draw from base ∪ sub ids, so the per-tile unique
-        # count can exceed the base cluster count — provision for the full
-        # id space or the dedup's overflow drop would break parity
-        k_total = kc + (cat.n_subs if cat is not None else 0)
-        full_cap = min(qb * width, k_total)
-        cap = full_cap if self.u_cap is None else self.u_cap
-        cast_dtype = (
-            np.dtype(np.float32) if index.quantized
-            else np.dtype(index.store_dtype)
-        )
-
-        (slot_cluster, slot_tile, slot_of_probe, probe_ok, n_unique,
-         queries_pad, lo_pad, hi_pad, n_pruned, geo_probes,
-         geo_valid) = plan_fused_tiled(
-            centroids, counts, queries, fspec.lo, fspec.hi,
-            metric=index.spec.metric, n_probes=self.n_probes, q_block=qb,
-            u_cap=cap, cast_dtype=cast_dtype, summaries=summ, t_max=t_max,
-            route_entry=route_entry, members=members,
-        )
-        qpad = queries_pad.shape[0]
-        n_tiles = qpad // qb
-
-        # The sync RAM fast path needs no host view of the tables; the
-        # pipelined / disk paths (per-tile slices, fetch lists) do.  The
-        # adaptive provisioner alone only needs the tiny [n_tiles] unique
-        # counts — the full tables come to host iff a shrink happens.
-        need_host = (self.pipeline == "on" or self._gather_fn is not None
-                     or self.termination is not None)
-        plan = SearchPlan(
-            q=q, q_block=qb, n_tiles=n_tiles, u_cap=cap, width=width,
-            slot_cluster=slot_cluster, slot_tile=slot_tile,
-            slot_of_probe=slot_of_probe, probe_ok=probe_ok,
-            n_unique=n_unique, queries=queries,
-            queries_orig_pad=(
-                probes_lib.pad_to_tiles(queries, qb)
-                if self.pipeline == "on" else None
-            ),
-            queries_pad=queries_pad, lo_pad=lo_pad, hi_pad=hi_pad,
-            n_pruned=n_pruned,
-            geo_probes=(geo_probes if snap is not None else None),
-            geo_valid=(geo_valid if snap is not None else None),
-            gens=self._plan_gens(),
-            delta_snap=snap,
-            route=route,
-        )
-        if self.adaptive_u_cap:
-            self._provision(plan)
-        if need_host:
-            self._host_tables(plan)
-        if self.termination is not None:
-            # reorders the slot tables best-bound-first and attaches the
-            # TermState; must run before any fetch list / TileWork exists so
-            # fetch order and prefetch follow the scan order
-            self._prepare_termination(plan, summ, counts)
-        self.stats.last_u_cap = plan.u_cap
-        self.stats.u_cap_hist[plan.u_cap] = (
-            self.stats.u_cap_hist.get(plan.u_cap, 0) + 1
-        )
-        self._observe_stage("plan", time.perf_counter() - t0)
+                # The sync RAM fast path needs no host view of the tables; the
+                # pipelined / disk paths (per-tile slices, fetch lists) do.
+                # The adaptive provisioner alone only needs the tiny [n_tiles]
+                # unique counts — the full tables come to host iff a shrink
+                # happens.
+                need_host = (self.pipeline == "on"
+                             or self._gather_fn is not None
+                             or self.termination is not None)
+            with self._stage("plan.device"):
+                (slot_cluster, slot_tile, slot_of_probe, probe_ok, n_unique,
+                 queries_pad, lo_pad, hi_pad, n_pruned, geo_probes,
+                 geo_valid) = plan_fused_tiled(
+                    centroids, counts, queries, fspec.lo, fspec.hi,
+                    metric=index.spec.metric, n_probes=self.n_probes,
+                    q_block=qb, u_cap=cap, cast_dtype=cast_dtype,
+                    summaries=summ, t_max=t_max,
+                    route_entry=route_entry, members=members,
+                )
+                if self.adaptive_u_cap or need_host:
+                    # the plan's first host read: it waits for the
+                    # plan program to finish on the device
+                    n_unique = np.asarray(n_unique)
+            with self._stage("plan.tables"):
+                qpad = queries_pad.shape[0]
+                n_tiles = qpad // qb
+                plan = SearchPlan(
+                    q=q, q_block=qb, n_tiles=n_tiles, u_cap=cap, width=width,
+                    slot_cluster=slot_cluster, slot_tile=slot_tile,
+                    slot_of_probe=slot_of_probe, probe_ok=probe_ok,
+                    n_unique=n_unique, queries=queries,
+                    queries_orig_pad=(
+                        probes_lib.pad_to_tiles(queries, qb)
+                        if self.pipeline == "on" else None
+                    ),
+                    queries_pad=queries_pad, lo_pad=lo_pad, hi_pad=hi_pad,
+                    n_pruned=n_pruned,
+                    geo_probes=(geo_probes if snap is not None else None),
+                    geo_valid=(geo_valid if snap is not None else None),
+                    gens=self._plan_gens(),
+                    delta_snap=snap,
+                    route=route,
+                )
+                if self.adaptive_u_cap:
+                    self._provision(plan)
+                if need_host:
+                    self._host_tables(plan)
+                if self.termination is not None:
+                    # reorders the slot tables best-bound-first and attaches
+                    # the TermState; must run before any fetch list / TileWork
+                    # exists so fetch order and prefetch follow the scan order
+                    self._prepare_termination(plan, summ, counts)
+                self.stats.last_u_cap = plan.u_cap
+                self.stats.u_cap_hist[plan.u_cap] = (
+                    self.stats.u_cap_hist.get(plan.u_cap, 0) + 1
+                )
         return plan
 
     def _plan_gens(self) -> Optional[np.ndarray]:
@@ -1593,14 +1498,14 @@ class SearchEngine:
         if self._gather_fn is None:
             return (plan.slot_cluster, index.vectors, index.attrs, index.ids,
                     index.norms, index.scales)
-        t0 = time.perf_counter()
-        if self._store is not None and self._gather_fn == self._store_gather:
-            out = self._store_gather(plan.slot_cluster, gens=plan.gens,
-                                     plan=plan)
-        else:
-            out = self._gather_fn(plan.slot_cluster)
+        with self._stage("fetch"):
+            if (self._store is not None
+                    and self._gather_fn == self._store_gather):
+                out = self._store_gather(plan.slot_cluster, gens=plan.gens,
+                                         plan=plan)
+            else:
+                out = self._gather_fn(plan.slot_cluster)
         slot_cluster, vectors, attrs, ids, norms, scales = out
-        self._observe_stage("fetch", time.perf_counter() - t0)
         return (jnp.asarray(slot_cluster), vectors, attrs, ids, norms,
                 scales)
 
@@ -1644,7 +1549,11 @@ class SearchEngine:
         snap = plan.delta_snap
         if snap is None or snap.n_rows == 0:
             return res
-        t0 = time.perf_counter()
+        with self._stage("delta_fold"):
+            return self._fold_snapshot(plan, snap, res)
+
+    def _fold_snapshot(self, plan: SearchPlan, snap,
+                       res: SearchResult) -> SearchResult:
         from repro.core import delta as delta_lib
 
         # Per-attribute interval pre-test: the delta tier keeps a running
@@ -1670,7 +1579,6 @@ class SearchEngine:
                     snap, plan.geo_probes, plan.geo_valid
                 )
                 q = plan.q
-                self._observe_stage("delta_fold", time.perf_counter() - t0)
                 return dataclasses.replace(
                     res, n_scanned=res.n_scanned + dscan[:q]
                 )
@@ -1688,14 +1596,11 @@ class SearchEngine:
         ).any()):
             self.stats.delta_skips += 1
             if summ is None:  # no live rows: reach is identically zero
-                self._observe_stage("delta_fold",
-                                    time.perf_counter() - t0)
                 return res
             dscan = delta_lib.snapshot_reach(
                 snap, plan.geo_probes, plan.geo_valid
             )
             q = plan.q
-            self._observe_stage("delta_fold", time.perf_counter() - t0)
             return dataclasses.replace(
                 res, n_scanned=res.n_scanned + dscan[:q]
             )
@@ -1710,7 +1615,6 @@ class SearchEngine:
             (res.scores, res.ids), (dvals[:q], dids[:q]), self.k
         )
         self.stats.delta_folds += 1
-        self._observe_stage("delta_fold", time.perf_counter() - t0)
         return dataclasses.replace(
             res, scores=vals, ids=out_ids,
             n_scanned=res.n_scanned + dscan[:q],
@@ -1719,54 +1623,55 @@ class SearchEngine:
 
     def scan_merge(self, plan: SearchPlan, operands) -> SearchResult:
         """Whole-batch scan/merge over fetched operands (sync executor)."""
-        t0 = time.perf_counter()
-        slot_cluster, vectors, attrs, ids, norms, scales = operands
-        ids = self._mask_tombstones(plan, ids)
-        metric = self.index.spec.metric
-        self._count_scan(self._scan_key(
-            plan, q=plan.q, qpad=plan.n_tiles * plan.q_block,
-            s=plan.n_tiles * plan.u_cap, q_block=plan.q_block,
-            vectors=vectors, norms=norms, scales=scales,
-        ))
-        res = _scan_merge_tiled(
-            jnp.asarray(slot_cluster), jnp.asarray(plan.slot_tile),
-            jnp.asarray(plan.slot_of_probe), jnp.asarray(plan.probe_ok),
-            plan.queries, plan.queries_pad, plan.lo_pad, plan.hi_pad,
-            vectors, attrs, ids, norms, scales,
-            metric=metric, k=self.k, q=plan.q, q_block=plan.q_block,
-            v_block=self.v_block, backend=self.backend,
-        )
-        self._observe_stage("scan", time.perf_counter() - t0)
+        # times the dispatch: the scan is still running on the device
+        with self._stage("scan_dispatch"):
+            slot_cluster, vectors, attrs, ids, norms, scales = operands
+            ids = self._mask_tombstones(plan, ids)
+            metric = self.index.spec.metric
+            self._count_scan(self._scan_key(
+                plan, q=plan.q, qpad=plan.n_tiles * plan.q_block,
+                s=plan.n_tiles * plan.u_cap, q_block=plan.q_block,
+                vectors=vectors, norms=norms, scales=scales,
+            ))
+            res = _scan_merge_tiled(
+                jnp.asarray(slot_cluster), jnp.asarray(plan.slot_tile),
+                jnp.asarray(plan.slot_of_probe), jnp.asarray(plan.probe_ok),
+                plan.queries, plan.queries_pad, plan.lo_pad, plan.hi_pad,
+                vectors, attrs, ids, norms, scales,
+                metric=metric, k=self.k, q=plan.q, q_block=plan.q_block,
+                v_block=self.v_block, backend=self.backend,
+            )
         return dataclasses.replace(res, n_pruned=plan.n_pruned)
 
     def _scan_tile(self, plan: SearchPlan, i: int, operands) -> SearchResult:
         """Scan/merge one query tile (pipelined executor).  Same jitted
         stage as the monolith with ``n_tiles=1`` — per-slot arithmetic is
         identical, so tile results concatenate to the sync result bitwise."""
-        t0 = time.perf_counter()
-        slot_cluster, vectors, attrs, ids, norms, scales = operands
-        ids = self._mask_tombstones(plan, ids)
-        qb, cap = plan.q_block, plan.u_cap
-        metric = self.index.spec.metric
-        if plan.queries_orig_pad is None:  # plan was built for a sync run
-            plan.queries_orig_pad = probes_lib.pad_to_tiles(plan.queries, qb)
-        rows = slice(i * qb, (i + 1) * qb)
-        sop = plan.slot_of_probe[rows] - i * cap  # tile-local slot pointers
-        self._count_scan(self._scan_key(
-            plan, q=qb, qpad=qb, s=cap, q_block=qb,
-            vectors=vectors, norms=norms, scales=scales,
-        ))
-        res = _scan_merge_tiled(
-            jnp.asarray(slot_cluster),
-            jnp.zeros((cap,), jnp.int32),
-            jnp.asarray(sop), jnp.asarray(plan.probe_ok[rows]),
-            plan.queries_orig_pad[rows], plan.queries_pad[rows],
-            plan.lo_pad[rows], plan.hi_pad[rows],
-            vectors, attrs, ids, norms, scales,
-            metric=metric, k=self.k, q=qb, q_block=qb,
-            v_block=self.v_block, backend=self.backend,
-        )
-        self._observe_stage("scan", time.perf_counter() - t0)
+        with self._stage("scan_dispatch"):
+            slot_cluster, vectors, attrs, ids, norms, scales = operands
+            ids = self._mask_tombstones(plan, ids)
+            qb, cap = plan.q_block, plan.u_cap
+            metric = self.index.spec.metric
+            if plan.queries_orig_pad is None:  # plan built for a sync run
+                plan.queries_orig_pad = probes_lib.pad_to_tiles(
+                    plan.queries, qb)
+            rows = slice(i * qb, (i + 1) * qb)
+            # tile-local slot pointers
+            sop = plan.slot_of_probe[rows] - i * cap
+            self._count_scan(self._scan_key(
+                plan, q=qb, qpad=qb, s=cap, q_block=qb,
+                vectors=vectors, norms=norms, scales=scales,
+            ))
+            res = _scan_merge_tiled(
+                jnp.asarray(slot_cluster),
+                jnp.zeros((cap,), jnp.int32),
+                jnp.asarray(sop), jnp.asarray(plan.probe_ok[rows]),
+                plan.queries_orig_pad[rows], plan.queries_pad[rows],
+                plan.lo_pad[rows], plan.hi_pad[rows],
+                vectors, attrs, ids, norms, scales,
+                metric=metric, k=self.k, q=qb, q_block=qb,
+                v_block=self.v_block, backend=self.backend,
+            )
         return res
 
     def _fetch_segment(self, plan: SearchPlan, seg_sc: np.ndarray,
@@ -1841,11 +1746,17 @@ class SearchEngine:
         :meth:`_fetch_segment`, so boundary drops shrink the remote fetch
         lists; ``ops`` is the batch-scoped record cache.
         """
+        # each segment boundary reads the running top-k back to the host,
+        # so this span times the scan on the device, not only its dispatch
+        with self._stage("scan"):
+            return self._scan_segments(plan, i, operands, ops)
+
+    def _scan_segments(self, plan: SearchPlan, i: int, operands,
+                       ops: Optional[Dict[int, dict]]) -> SearchResult:
         from repro.kernels.filtered_scan.filtered_scan import (
             fold_running_topk,
         )
 
-        t_start = time.perf_counter()
         term = plan.term
         qb, cap, k = plan.q_block, plan.u_cap, self.k
         seg, n_seg = term.seg, term.n_seg
@@ -1898,12 +1809,11 @@ class SearchEngine:
             else:
                 scanned[si] = True
                 if segmented:
-                    t_f = time.perf_counter()
-                    (seg_local, vectors, attrs, ids, norms,
-                     scales) = self._fetch_segment(
-                        plan, sc[p0:p1], alive_seg, ops
-                    )
-                    self._observe_stage("fetch", time.perf_counter() - t_f)
+                    with self._stage("fetch"):
+                        (seg_local, vectors, attrs, ids, norms,
+                         scales) = self._fetch_segment(
+                            plan, sc[p0:p1], alive_seg, ops
+                        )
                     ids = self._mask_tombstones(plan, ids)
                     live_row = np.asarray(
                         jnp.sum((ids >= 0).astype(jnp.int32), axis=-1)
@@ -1991,7 +1901,6 @@ class SearchEngine:
             plan.queries_orig_pad[rows], live_per_slot,
             metric=metric, k=k, q=qb,
         )
-        self._observe_stage("scan", time.perf_counter() - t_start)
         return res
 
     def _execute_terminated_sync(self, plan: SearchPlan) -> SearchResult:
@@ -2352,20 +2261,19 @@ class SearchEngine:
 
     def _merge_parts(self, plan: SearchPlan,
                      parts: List[SearchResult]) -> SearchResult:
-        t0 = time.perf_counter()
-        if len(parts) == 1:
-            res = parts[0]
-            res = SearchResult(res.scores[: plan.q], res.ids[: plan.q],
-                               res.n_scanned[: plan.q],
-                               res.n_passed[: plan.q])
-        else:
-            res = SearchResult(
-                jnp.concatenate([p.scores for p in parts])[: plan.q],
-                jnp.concatenate([p.ids for p in parts])[: plan.q],
-                jnp.concatenate([p.n_scanned for p in parts])[: plan.q],
-                jnp.concatenate([p.n_passed for p in parts])[: plan.q],
-            )
-        self._observe_stage("merge", time.perf_counter() - t0)
+        with self._stage("merge_dispatch"):
+            if len(parts) == 1:
+                res = parts[0]
+                res = SearchResult(res.scores[: plan.q], res.ids[: plan.q],
+                                   res.n_scanned[: plan.q],
+                                   res.n_passed[: plan.q])
+            else:
+                res = SearchResult(
+                    jnp.concatenate([p.scores for p in parts])[: plan.q],
+                    jnp.concatenate([p.ids for p in parts])[: plan.q],
+                    jnp.concatenate([p.n_scanned for p in parts])[: plan.q],
+                    jnp.concatenate([p.n_passed for p in parts])[: plan.q],
+                )
         return dataclasses.replace(res, n_pruned=plan.n_pruned)
 
     # ---- the whole pipeline ----

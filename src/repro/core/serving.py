@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.filters import FilterSpec, match_all
+from repro.core.obs import span
 
 Array = jax.Array
 logger = logging.getLogger(__name__)
@@ -293,6 +294,8 @@ class Response:
     #                 layer served around an open peer circuit (the latter
     #                 keeps results bit-identical — it is a health signal,
     #                 not a recall warning)
+    batch: int  # the server's batch number: the stat ``batch`` of the
+    #             batch's ``repro.server.*`` spans
 
 
 class ShardHealth:
@@ -324,6 +327,16 @@ class SearchServer:
 
     search_fn(queries [Q, D], fspec, shard_ok [S]) -> (scores [Q,k], ids [Q,k])
     with STATIC Q — the server pads tail batches.
+
+    The serving loop is tiled by five spans (``repro.core.obs.span``), each
+    with the batch number as stat ``batch`` and a running sum of seconds in
+    ``stats``: ``server.drain`` (``drain_s``, waiting until a batch is
+    formed), ``server.assemble`` (``assemble_s``, stacking the requests and
+    the host-to-device puts), ``server.dispatch`` (``dispatch_s``, the call
+    into ``search_fn``), ``server.wait`` (``wait_s``, the answers to the
+    host: device completion and copy) and ``server.deliver``
+    (``deliver_s``, bookkeeping and the responses).  ``total_latency_s`` is
+    ``dispatch_s + wait_s``, updated in the same step.
     """
 
     def __init__(
@@ -350,7 +363,9 @@ class SearchServer:
         self._worker: Optional[threading.Thread] = None
         self.stats = dict(batches=0, requests=0, degraded_batches=0,
                           failed_batches=0, total_latency_s=0.0,
-                          refreshes=0)
+                          refreshes=0, drain_s=0.0, assemble_s=0.0,
+                          dispatch_s=0.0, wait_s=0.0, deliver_s=0.0)
+        self._next_batch = 0  # the number of the batch being formed
 
     # ---- client side ----
     def submit(self, query: np.ndarray, fspec_row: Optional[Tuple] = None
@@ -434,14 +449,27 @@ class SearchServer:
             refresh()
             self.stats["refreshes"] += 1
 
+    def _span(self, stage: str, batch: int) -> span:
+        """Span ``repro.server.<stage>`` adding its seconds to
+        ``stats["<stage>_s"]``."""
+        key = f"{stage}_s"
+
+        def sink(seconds: float):
+            self.stats[key] += seconds
+
+        return span(f"server.{stage}", sink, batch=batch)
+
     def _run(self):
         while not self._stop.is_set():
             self._maybe_refresh()
-            batch = self._drain()
+            n = self._next_batch
+            with self._span("drain", n):
+                batch = self._drain()
             if not batch:
                 continue
+            self._next_batch = n + 1
             try:
-                self._serve(batch)
+                self._serve(batch, n)
             except Exception as e:  # the loop must outlive a failed batch
                 logger.exception("search batch of %d requests failed",
                                  len(batch))
@@ -450,43 +478,53 @@ class SearchServer:
                     if r.future.empty():
                         r.future.put(e)
 
-    def _serve(self, batch: List[Request]):
+    def _serve(self, batch: List[Request], n: int):
         b = len(batch)
         qsz = self.batch_size
-        queries = np.zeros((qsz, self.dim), np.float32)
-        lo = np.zeros((qsz, self.n_terms, self.n_attrs), np.int16)
-        hi = np.zeros((qsz, self.n_terms, self.n_attrs), np.int16)
-        for i, r in enumerate(batch):
-            queries[i] = r.query
-            lo[i] = r.lo
-            hi[i] = r.hi
-        ok = self.health.ok_mask()
-        fspec = FilterSpec(lo=jnp.asarray(lo), hi=jnp.asarray(hi))
-        t0 = time.monotonic()
-        scores, ids = self.search_fn(
-            jnp.asarray(queries), fspec, jnp.asarray(ok)
-        )
-        scores = np.asarray(scores)
-        ids = np.asarray(ids)
+        with self._span("assemble", n):
+            queries = np.zeros((qsz, self.dim), np.float32)
+            lo = np.zeros((qsz, self.n_terms, self.n_attrs), np.int16)
+            hi = np.zeros((qsz, self.n_terms, self.n_attrs), np.int16)
+            for i, r in enumerate(batch):
+                queries[i] = r.query
+                lo[i] = r.lo
+                hi[i] = r.hi
+            fspec = FilterSpec(lo=jnp.asarray(lo), hi=jnp.asarray(hi))
+            queries = jnp.asarray(queries)
+            ok = jnp.asarray(self.health.ok_mask())
+        # dispatch and wait reach stats together with total_latency_s in
+        # the deliver step, so every copy of stats has it equal to their sum
+        with span("server.dispatch", None, batch=n) as dispatch:
+            scores, ids = self.search_fn(queries, fspec, ok)
+        with span("server.wait", None, batch=n) as wait:
+            scores = np.asarray(scores)
+            ids = np.asarray(ids)
         t1 = time.monotonic()
-        # degraded = a shard dropped from the merge OR the fetch layer
-        # routing around an open peer circuit (results stay bit-identical
-        # in the latter case; clients still deserve the signal)
-        store_degraded = getattr(self.search_fn, "degraded", None)
-        degraded = self.health.degraded or bool(
-            store_degraded() if callable(store_degraded) else False
-        )
-        self.stats["batches"] += 1
-        self.stats["requests"] += b
-        self.stats["degraded_batches"] += int(degraded)
-        self.stats["total_latency_s"] += t1 - t0
-        for i, r in enumerate(batch):
-            r.future.put(
-                Response(
-                    scores=scores[i],
-                    ids=ids[i],
-                    latency_s=t1 - r.t_enqueue,
-                    batched_with=b,
-                    degraded=degraded,
-                )
+        with self._span("deliver", n):
+            # degraded = a shard dropped from the merge OR the fetch layer
+            # routing around an open peer circuit (results stay
+            # bit-identical in the latter case; clients still deserve the
+            # signal)
+            store_degraded = getattr(self.search_fn, "degraded", None)
+            degraded = self.health.degraded or bool(
+                store_degraded() if callable(store_degraded) else False
             )
+            st = self.stats
+            dispatch_s = st["dispatch_s"] + dispatch.seconds
+            wait_s = st["wait_s"] + wait.seconds
+            st.update(batches=st["batches"] + 1,
+                      requests=st["requests"] + b,
+                      degraded_batches=st["degraded_batches"] + int(degraded),
+                      dispatch_s=dispatch_s, wait_s=wait_s,
+                      total_latency_s=dispatch_s + wait_s)
+            for i, r in enumerate(batch):
+                r.future.put(
+                    Response(
+                        scores=scores[i],
+                        ids=ids[i],
+                        latency_s=t1 - r.t_enqueue,
+                        batched_with=b,
+                        degraded=degraded,
+                        batch=n,
+                    )
+                )

@@ -275,12 +275,22 @@ def main():
         termination=args.termination, epsilon=args.epsilon,
         partitions=args.partitions,
     )
+    server = SearchServer(
+        search_fn, batch_size=args.batch, dim=serving_index.spec.dim,
+        n_attrs=serving_index.spec.n_attrs, n_terms=1, n_shards=8,
+    )
     metrics_httpd = None
     if args.metrics_port is not None:
         import http.server
         import threading
 
-        metrics_text = search_fn.metrics_text
+        from repro.core.obs import render_prometheus
+
+        def metrics_text() -> str:
+            # the engine's scrape, then the serving loop's stage sums
+            # (repro_server_drain_s, ..._wait_s, ...) and batch counts
+            return search_fn.metrics_text() + render_prometheus(
+                dict(server.stats), prefix="repro_server")
 
         class _MetricsHandler(http.server.BaseHTTPRequestHandler):
             def do_GET(self):  # noqa: N802 (http.server API)
@@ -311,10 +321,6 @@ def main():
               f"({args.cache_transport} transport), ring "
               f"{bs.ownership.__class__.__name__}")
 
-    server = SearchServer(
-        search_fn, batch_size=args.batch, dim=serving_index.spec.dim,
-        n_attrs=serving_index.spec.n_attrs, n_terms=1, n_shards=8,
-    )
     server.start()
     rng = np.random.default_rng(1)
     t0 = time.time()

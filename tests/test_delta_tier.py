@@ -782,8 +782,10 @@ def test_delta_quantize_republish_dequantizes(built_dot, tmp_path):
 
 def test_metrics_text_stage_latency_histograms(built_dot, tmp_path):
     """Satellite: fixed-bucket Prometheus latency histograms per pipeline
-    stage — plan/fetch/scan/merge/delta_fold — with classic cumulative
-    ``le`` semantics and matching ``_count``/``_sum`` rows."""
+    stage — plan/fetch/scan_dispatch/merge_dispatch/delta_fold (the
+    pipelined executor's scan and merge timers end at dispatch) — with
+    classic cumulative ``le`` semantics and matching ``_count``/``_sum``
+    rows."""
     index, centers, core, attrs, topic = built_dot
     disk, tier = _open_live(index, str(tmp_path / "ck"))
     tier.add(core[:4], attrs[:4].astype(np.int16),
@@ -795,7 +797,8 @@ def test_metrics_text_stage_latency_histograms(built_dot, tmp_path):
         eng.search(jnp.asarray(core[:21]), match_all(21, M))
     text = eng.metrics_text()
     assert "# TYPE repro_stage_latency_seconds histogram" in text
-    for stage in ("plan", "fetch", "scan", "merge", "delta_fold"):
+    for stage in ("plan", "fetch", "scan_dispatch", "merge_dispatch",
+                  "delta_fold"):
         bucket_counts = []
         for line in text.splitlines():
             if (line.startswith("repro_stage_latency_seconds_bucket")
