@@ -248,7 +248,7 @@ def _local_shard_search(
                 norms, scales, metric=metric, k=k, q_block=q_block,
             )
         else:
-            uvals, uids, _ = filtered_scan_tiled(
+            uvals, uids, _, _ = filtered_scan_tiled(
                 u_cluster, u_tile, queries, lo, hi, vectors, attrs, ids,
                 norms, scales, metric=metric, k=k, q_block=q_block,
                 v_block=v_block,

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -287,7 +288,7 @@ def _scan_merge_tiled(
     q_block: int,
     v_block: int,
     backend: str,
-) -> SearchResult:
+) -> Tuple[SearchResult, Optional[Array]]:
     """Scan + merge stages: scan the planned slots, merge per-probe fragments.
 
     ``vectors/attrs/ids/...`` are indexed by ``slot_cluster`` rows — either
@@ -297,26 +298,17 @@ def _scan_merge_tiled(
     are indistinguishable to it.  The pipelined executor calls this once per
     tile (``q = q_block``, ``slot_tile ≡ 0``) with identical per-slot
     arithmetic, so its results are bit-identical to one whole-batch call.
-    """
-    from repro.kernels.filtered_scan.filtered_scan import filtered_scan_tiled
 
+    Returns the result and the kernel's top-k insertion passes summed over
+    the slots (a device scalar; None on the ``xla`` executor, which has no
+    such fold).
+    """
     qpad = queries_pad.shape[0]
-    with jax.named_scope("scan"):
-        if backend in ("pallas", "pallas_interpret"):
-            svals, sids, snpass = filtered_scan_tiled(
-                slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
-                vectors, attrs, ids, norms, scales,
-                metric=metric, k=k, q_block=q_block, v_block=v_block,
-                interpret=backend == "pallas_interpret",
-            )
-        elif backend == "xla":
-            svals, sids, snpass = tiled_scan_xla(
-                slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
-                vectors, attrs, ids, norms, scales,
-                metric=metric, k=k, q_block=q_block,
-            )
-        else:
-            raise ValueError(backend)
+    svals, sids, snpass, fold = _scan_slots(
+        slot_cluster, queries_pad, lo_pad, hi_pad, vectors, attrs, ids,
+        norms, scales, slot_tile, metric=metric, k=k, q_block=q_block,
+        v_block=v_block, backend=backend,
+    )
 
     with jax.named_scope("merge"):
         # Per-probe candidate fragments, then the monoid merge across T
@@ -352,7 +344,7 @@ def _scan_merge_tiled(
             * probe_ok[:q].astype(jnp.int32),
             axis=-1,
         )
-        return SearchResult(vals, out_ids, n_scanned, n_passed)
+        return SearchResult(vals, out_ids, n_scanned, n_passed), fold
 
 
 @functools.partial(
@@ -361,8 +353,8 @@ def _scan_merge_tiled(
 )
 @jax.named_scope("scan")
 def _scan_slots(
-    slot_cluster: Array,   # [S] rows into the operand arrays (one segment)
-    queries_pad: Array,    # [QB, D] one tile's cast queries
+    slot_cluster: Array,   # [S] rows into the operand arrays
+    queries_pad: Array,    # [Qpad, D] cast + tile-padded queries
     lo_pad: Array,
     hi_pad: Array,
     vectors: Array,
@@ -370,6 +362,7 @@ def _scan_slots(
     ids: Array,
     norms: Optional[Array],
     scales: Optional[Array],
+    slot_tile: Optional[Array] = None,  # [S] tile of each slot; None ⇒ 0
     *,
     metric: str,
     k: int,
@@ -377,30 +370,34 @@ def _scan_slots(
     v_block: int,
     backend: str,
 ):
-    """Scan stage alone: one slot segment's ``[S, QB, k]`` fragments.
+    """Scan stage alone: the slots' ``[S, QB, k]`` fragments, their
+    ``[S, QB]`` pass counts, and the kernel's top-k insertion passes summed
+    over the slots (None on the ``xla`` executor).
 
-    Exactly :func:`_scan_merge_tiled`'s scan half over a slice of a tile's
-    slot table (``slot_tile ≡ 0`` — one query tile).  Per-slot arithmetic is
+    :func:`_scan_merge_tiled`'s scan half; the bound-driven executor calls
+    it on a slice of one tile's slot table.  Per-slot arithmetic is
     independent of which other slots share the call, so fragments from
     segmented scans are bitwise the fragments one whole-table scan produces
     — the bound-driven executor's exactness rides on that.
     """
     from repro.kernels.filtered_scan.filtered_scan import filtered_scan_tiled
 
-    slot_tile = jnp.zeros((slot_cluster.shape[0],), jnp.int32)
+    if slot_tile is None:
+        slot_tile = jnp.zeros((slot_cluster.shape[0],), jnp.int32)
     if backend in ("pallas", "pallas_interpret"):
-        return filtered_scan_tiled(
+        svals, sids, snpass, nfold = filtered_scan_tiled(
             slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
             vectors, attrs, ids, norms, scales,
             metric=metric, k=k, q_block=q_block, v_block=v_block,
             interpret=backend == "pallas_interpret",
         )
-    elif backend == "xla":
+        return svals, sids, snpass, jnp.sum(nfold)
+    if backend == "xla":
         return tiled_scan_xla(
             slot_cluster, slot_tile, queries_pad, lo_pad, hi_pad,
             vectors, attrs, ids, norms, scales,
             metric=metric, k=k, q_block=q_block,
-        )
+        ) + (None,)
     raise ValueError(backend)
 
 
@@ -711,6 +708,12 @@ class EngineStats:
     # delta folds skipped by the per-attribute running interval envelope
     # (satellite of the summary-based delta_skips; also counted there)
     delta_interval_skips: int = 0
+    # the scan kernel's top-k fold: insertion passes run, and k passes per
+    # grid step (what a fold of fixed length runs); passes ÷ k_passes is the
+    # share of that fold the data needed.  Summed from the kernel's device
+    # counts when the engine's metrics are read, finished scans only.
+    fold_passes: int = 0
+    fold_k_passes: int = 0
 
     @property
     def overlap_ratio(self) -> float:
@@ -725,6 +728,10 @@ class EngineStats:
 # mirrors the underlying jit cache (which is also process-wide), so a new key
 # here == a real XLA compilation.
 _SCAN_KEYS: set = set()
+
+# Scans whose fold counts wait unread before the engine sums the finished
+# ones itself (a server whose metrics nobody reads).
+_FOLD_PENDING_MAX = 1024
 
 
 def scan_compile_count() -> int:
@@ -949,6 +956,9 @@ class SearchEngine:
         # appended to metrics_text() for the Prometheus scrape
         self._stage_hist: Dict[str, StageHistogram] = {}
         self.stats = EngineStats()
+        # the scans' top-k insertion passes, still on the device
+        self._fold_pending: List[Tuple[Array, int]] = []
+        self._fold_lock = threading.Lock()
 
     def _observe_stage(self, stage: str, seconds: float):
         hist = self._stage_hist.get(stage)
@@ -1510,6 +1520,34 @@ class SearchEngine:
                 scales)
 
     # ---- scan + merge ----
+    def _note_fold(self, fold, n_slots: int, vectors):
+        """Keeps a scan's top-k insertion passes (``fold``, a device scalar;
+        None off the kernel) beside the k passes per grid step that a fold
+        of fixed length would run.  Nothing is read here: :meth:`metrics`
+        sums the counts of the scans that have finished."""
+        from repro.kernels.filtered_scan.filtered_scan import fold_steps
+
+        if fold is None:
+            return
+        steps = fold_steps(int(np.shape(vectors)[1]), self.v_block)
+        with self._fold_lock:
+            self._fold_pending.append((fold, n_slots * steps * self.k))
+        if len(self._fold_pending) >= _FOLD_PENDING_MAX:
+            self._collect_fold()  # nothing reads the stats: keep it bounded
+
+    def _collect_fold(self):
+        """Adds the finished scans' insertion passes into the stats; those
+        still on the device wait for the next read."""
+        with self._fold_lock:
+            done, wait = [], []
+            for p in self._fold_pending:
+                (done if p[0].is_ready() else wait).append(p)
+            self._fold_pending = wait
+            if done:
+                passes = jax.device_get([p[0] for p in done])
+                self.stats.fold_passes += sum(int(x) for x in passes)
+                self.stats.fold_k_passes += sum(p[1] for p in done)
+
     def _count_scan(self, key: Tuple):
         if key not in _SCAN_KEYS:
             _SCAN_KEYS.add(key)
@@ -1633,7 +1671,7 @@ class SearchEngine:
                 s=plan.n_tiles * plan.u_cap, q_block=plan.q_block,
                 vectors=vectors, norms=norms, scales=scales,
             ))
-            res = _scan_merge_tiled(
+            res, fold = _scan_merge_tiled(
                 jnp.asarray(slot_cluster), jnp.asarray(plan.slot_tile),
                 jnp.asarray(plan.slot_of_probe), jnp.asarray(plan.probe_ok),
                 plan.queries, plan.queries_pad, plan.lo_pad, plan.hi_pad,
@@ -1641,6 +1679,7 @@ class SearchEngine:
                 metric=metric, k=self.k, q=plan.q, q_block=plan.q_block,
                 v_block=self.v_block, backend=self.backend,
             )
+            self._note_fold(fold, plan.n_tiles * plan.u_cap, vectors)
         return dataclasses.replace(res, n_pruned=plan.n_pruned)
 
     def _scan_tile(self, plan: SearchPlan, i: int, operands) -> SearchResult:
@@ -1662,7 +1701,7 @@ class SearchEngine:
                 plan, q=qb, qpad=qb, s=cap, q_block=qb,
                 vectors=vectors, norms=norms, scales=scales,
             ))
-            res = _scan_merge_tiled(
+            res, fold = _scan_merge_tiled(
                 jnp.asarray(slot_cluster),
                 jnp.zeros((cap,), jnp.int32),
                 jnp.asarray(sop), jnp.asarray(plan.probe_ok[rows]),
@@ -1672,6 +1711,7 @@ class SearchEngine:
                 metric=metric, k=self.k, q=qb, q_block=qb,
                 v_block=self.v_block, backend=self.backend,
             )
+            self._note_fold(fold, cap, vectors)
         return res
 
     def _fetch_segment(self, plan: SearchPlan, seg_sc: np.ndarray,
@@ -1829,12 +1869,13 @@ class SearchEngine:
                     str(q_pad.dtype), tuple(lo_pad.shape[1:]),
                     norms is None, scales is None,
                 ))
-                svals, sids, snpass = _scan_slots(
+                svals, sids, snpass, fold = _scan_slots(
                     scan_sc, q_pad, lo_pad, hi_pad,
                     vectors, attrs, ids, norms, scales,
                     metric=metric, k=k, q_block=qb, v_block=self.v_block,
                     backend=self.backend,
                 )
+                self._note_fold(fold, seg, vectors)
                 frags.append((svals, sids, snpass))
                 run_vals, run_ids = fold_running_topk(
                     run_vals, run_ids, svals, sids, jnp.asarray(alive_seg),
@@ -2315,6 +2356,7 @@ class SearchEngine:
         ``delta.rows``, ...).  Values are scalars (numbers / bools /
         strings) — ready for a metrics exporter, no nesting to unpack."""
         out: Dict[str, Any] = {}
+        self._collect_fold()
         eng = dataclasses.asdict(self.stats)
         eng["overlap_ratio"] = self.stats.overlap_ratio
         eng["pipeline"] = self.pipeline

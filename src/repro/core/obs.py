@@ -92,6 +92,7 @@ _PROM_COUNTERS = frozenset((
     "probes_terminated", "term_segments_skipped",
     "partition_hits", "partition_fallbacks", "partition_rows_scanned",
     "flat_rows_scanned", "delta_interval_skips", "fetches_skipped",
+    "fold_passes", "fold_k_passes",
 ))
 
 

@@ -11,9 +11,11 @@ Two kernel generations live here:
     ``core/probes.py``), and the grid becomes ``(unique_slots, Vpad //
     v_block)``: each step scores a whole query tile against the streamed
     block in one ``[QB, D] @ [D, VB]`` matmul and folds the masked scores
-    into a running per-slot top-k held in the revisited output block — the
-    ``[P, Vpad]`` score matrix is never materialized, and peak memory drops
-    from ``O(Q·T·Vpad)`` to ``O(slots·QB·k)``.
+    into a running per-slot top-k held in VMEM scratch — the ``[P, Vpad]``
+    score matrix is never materialized, and peak memory drops from
+    ``O(Q·T·Vpad)`` to ``O(slots·QB·k)``.  The fold inserts only the block
+    entries that beat a query's running kth, one pass per entry, so once
+    the running sets fill most blocks cost a few passes or none.
 
 
 The paper's measured bottleneck is the *filtering pass* (1.09 s of 1.428 s):
@@ -58,6 +60,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -3.0e38
+
+
+def _fit_v_block(vpad: int, v_block: int) -> int:
+    """The row block the scans use: ``v_block`` capped at ``vpad`` and
+    halved until it divides ``vpad`` (builds pad lists to a multiple of 128,
+    so 128 always divides)."""
+    v_block = min(v_block, vpad)
+    while vpad % v_block != 0 and v_block > 8:
+        v_block //= 2
+    if vpad % v_block != 0:
+        raise ValueError(f"vpad={vpad} has no usable v_block ≤ requested")
+    return v_block
 
 
 def _mask_from_attrs(attrs_i32, lo_i32, hi_i32):
@@ -181,11 +195,7 @@ def filtered_scan(
     k, vpad, d = vectors.shape
     m = attrs.shape[-1]
     f = lo.shape[1]
-    v_block = min(v_block, vpad)
-    while vpad % v_block != 0 and v_block > 8:
-        v_block //= 2  # builds pad Vpad to ×128, so 128 always divides
-    if vpad % v_block != 0:
-        raise ValueError(f"vpad={vpad} has no usable v_block ≤ requested")
+    v_block = _fit_v_block(vpad, v_block)
     if metric not in ("dot", "l2"):
         raise ValueError(metric)
     if metric == "l2" and norms is None:
@@ -260,51 +270,55 @@ def filtered_scan(
 # ---------------------------------------------------------------------------
 
 
-def _fold_topk(run_v, run_i, scores, ids_blk, k):
-    """Monoid fold: best k of (running set ∪ block), by iterative extraction.
+def fold_steps(vpad: int, v_block: int) -> int:
+    """Grid steps of :func:`filtered_scan_tiled` per slot: the row blocks of
+    one list.  ``slots × fold_steps × k`` is what a fold of k passes per
+    step would run, the denominator of the kernel's pass count."""
+    return vpad // _fit_v_block(vpad, v_block)
 
-    Branch-free static-k max-extraction (the centroid_topk idiom) — no
-    reliance on sort/top_k lowering inside the kernel.  Ties resolve to the
-    earliest candidate position, running set first and then the block in
-    slot order, which reproduces ``lax.top_k``'s first-index tie order over
-    the flat list.
 
-    Shapes: ``run_v/run_i [QB, k]``, ``scores [QB, VB]``, ``ids_blk [1, VB]``.
-    The two candidate sets are never concatenated (a lane-unaligned concat
-    at k=100), every reduction keeps its lane axis (``[QB, 1]`` columns), and
-    the picked id is selected through the one-hot position mask rather than
-    an in-kernel gather, so the body lowers to plain VPU/XLU ops in Mosaic.
+def _insert_topk(run_v, run_i, scores, ids_blk, n):
+    """Folds a block into the running top-k by ``n`` insertion passes.
+
+    ``run_v/run_i [QB, KP]`` hold each query's running set sorted
+    descending in the first k lanes; the lanes past k hold values no
+    greater than the kth.  ``scores [QB, VB]`` is the masked block,
+    ``ids_blk [1, VB]`` its ids.  Each pass takes every query's best
+    remaining block entry ``b`` (earliest position on ties) and inserts it
+    at ``p = #(run_v >= b)``, shifting the lanes from ``p`` one to the
+    right; ``p >= k`` exactly when ``b`` does not beat the kth, and then the
+    first k lanes stay as they were.  Running entries precede equal block
+    entries and block entries keep their order, which is ``lax.top_k``'s
+    first-index order over the flat list.  A caller passes ``n`` = the
+    most block entries any query of the tile has above its kth (at most k):
+    no later entry can enter.
+
+    Every reduction keeps its lane axis (``[QB, 1]`` columns), the id is
+    selected through a one-hot mask, and the shift is a full-lane roll, so
+    the body lowers to plain VPU/XLU ops in Mosaic.
     """
     big = jnp.int32(2**30)
     col_k = jax.lax.broadcasted_iota(jnp.int32, run_v.shape, 1)
     col_b = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
 
-    def extract(j, carry):
-        run_v, blk_v, out_v, out_i = carry
-        m = jnp.maximum(
-            jnp.max(run_v, axis=1, keepdims=True),
-            jnp.max(blk_v, axis=1, keepdims=True),
-        )  # [QB, 1]
-        pos_r = jnp.min(jnp.where(run_v == m, col_k, big), axis=1,
-                        keepdims=True)
-        pos_b = jnp.min(jnp.where(blk_v == m, col_b, big), axis=1,
-                        keepdims=True)
-        in_run = pos_r < big  # earliest position: the running set wins ties
-        hit_r = jnp.logical_and(col_k == pos_r, in_run)
-        hit_b = jnp.logical_and(col_b == pos_b, jnp.logical_not(in_run))
-        picked = (jnp.sum(jnp.where(hit_r, run_i, 0), axis=1, keepdims=True)
-                  + jnp.sum(jnp.where(hit_b, ids_blk, 0), axis=1,
-                            keepdims=True))
-        out_v = jnp.where(col_k == j, m, out_v)
-        out_i = jnp.where(col_k == j, jnp.where(m > NEG_INF / 2, picked, -1),
-                          out_i)
-        return (jnp.where(hit_r, NEG_INF, run_v),
-                jnp.where(hit_b, NEG_INF, blk_v), out_v, out_i)
+    def insert(_, carry):
+        run_v, run_i, blk_v = carry
+        b = jnp.max(blk_v, axis=1, keepdims=True)  # [QB, 1]
+        pos = jnp.min(jnp.where(blk_v == b, col_b, big), axis=1,
+                      keepdims=True)
+        hit = col_b == pos
+        b_id = jnp.sum(jnp.where(hit, ids_blk, 0), axis=1, keepdims=True)
+        p = jnp.sum((run_v >= b).astype(jnp.int32), axis=1, keepdims=True)
+        keep = col_k < p
+        at = col_k == p
+        run_v = jnp.where(keep, run_v,
+                          jnp.where(at, b, pltpu.roll(run_v, 1, 1)))
+        run_i = jnp.where(keep, run_i,
+                          jnp.where(at, b_id, pltpu.roll(run_i, 1, 1)))
+        return run_v, run_i, jnp.where(hit, NEG_INF, blk_v)
 
-    _, _, out_v, out_i = jax.lax.fori_loop(
-        0, k, extract, (run_v, scores, run_v, run_i)
-    )
-    return out_v, out_i
+    run_v, run_i, _ = jax.lax.fori_loop(0, n, insert, (run_v, run_i, scores))
+    return run_v, run_i
 
 
 def _tiled_kernel(
@@ -317,7 +331,7 @@ def _tiled_kernel(
     a_ref,  # [1, M, VB] int16
     id_ref,  # [1, 1, VB]
     *rest,  # ([aux_ref [1,1,VB]], ov_ref [1,QB,k], oi_ref [1,QB,k],
-    #          op_ref [1,QB,1])
+    #          op_ref [1,QB,1], of_ref [1,1,1], rv_ref [QB,KP], ri_ref [QB,KP])
     k: int,
     metric: str,
     quantized: bool,
@@ -326,17 +340,18 @@ def _tiled_kernel(
 ):
     del slot_cluster_ref, slot_tile_ref
     if metric == "l2" or quantized:
-        aux_ref, ov_ref, oi_ref, op_ref = rest
+        aux_ref, *rest = rest
     else:
         aux_ref = None
-        ov_ref, oi_ref, op_ref = rest
+    ov_ref, oi_ref, op_ref, of_ref, rv_ref, ri_ref = rest
     vi = pl.program_id(1)
 
     @pl.when(vi == 0)
     def _init():
-        ov_ref[...] = jnp.full_like(ov_ref, NEG_INF)
-        oi_ref[...] = jnp.full_like(oi_ref, -1)
+        rv_ref[...] = jnp.full_like(rv_ref, NEG_INF)
+        ri_ref[...] = jnp.full_like(ri_ref, -1)
         op_ref[...] = jnp.zeros_like(op_ref)
+        of_ref[...] = jnp.zeros_like(of_ref)
 
     q = q_ref[...].astype(jnp.float32)  # [QB, D]
     v = v_ref[0].astype(jnp.float32)  # [VB, D]
@@ -374,9 +389,29 @@ def _tiled_kernel(
     op_ref[0] = op_ref[0] + jnp.sum(mask.astype(jnp.int32), axis=1,
                                     keepdims=True)
 
-    new_v, new_i = _fold_topk(ov_ref[0], oi_ref[0], scores, id_ref[0], k)
-    ov_ref[0] = new_v
-    oi_ref[0] = new_i
+    # Only block entries strictly above a query's kth can enter its top-k
+    # (an equal one loses the tie to the running set), and they enter in
+    # the block's descending order: the fold runs as many insertion passes
+    # as the tile's most such entries, capped at k, and none on a block
+    # that beats no kth.
+    run_v = rv_ref[...]
+    col_k = jax.lax.broadcasted_iota(jnp.int32, run_v.shape, 1)
+    kth = jnp.max(jnp.where(col_k == k - 1, run_v, NEG_INF), axis=1,
+                  keepdims=True)  # [QB, 1]
+    above = jnp.sum((scores > kth).astype(jnp.int32), axis=1, keepdims=True)
+    n = jnp.max(jnp.minimum(above, k))
+    of_ref[0] = of_ref[0] + n
+
+    @pl.when(n > 0)
+    def _fold():
+        new_v, new_i = _insert_topk(run_v, ri_ref[...], scores, id_ref[0], n)
+        rv_ref[...] = new_v
+        ri_ref[...] = new_i
+
+    @pl.when(vi == pl.num_programs(1) - 1)
+    def _emit():
+        ov_ref[0] = rv_ref[:, :k]
+        oi_ref[0] = ri_ref[:, :k]
 
 
 @functools.partial(
@@ -417,10 +452,13 @@ def filtered_scan_tiled(
       vals  [S, QB, k] f32 — per-slot streaming top-k (NEG_INF pads)
       ids   [S, QB, k] int32 — original vector ids (-1 pads)
       npass [S, QB] int32 — candidates passing filter ∧ liveness per slot
+      nfold [S] int32 — top-k insertion passes each slot ran, at most k per
+                        grid step (:func:`fold_steps` steps per slot)
 
     VMEM working set per step is ``QB·D + 4·QB·F·M + v_block·(D·bytes +
-    M·2 + 8) + 2·QB·k`` — 64×768 queries + 256×768 bf16 block ≈ 0.6 MiB,
-    far inside the ~16 MiB v5e budget, leaving room for double buffering.
+    M·2 + 8) + 4·QB·round_up(k, 128)`` — 64×768 queries + 256×768 bf16
+    block ≈ 0.6 MiB, far inside the ~16 MiB v5e budget, leaving room for
+    double buffering.
     """
     s = slot_cluster.shape[0]
     qpad, d = queries.shape
@@ -429,11 +467,7 @@ def filtered_scan_tiled(
     f = lo.shape[1]
     if qpad % q_block:
         raise ValueError(f"Qpad={qpad} not a multiple of q_block={q_block}")
-    v_block = min(v_block, vpad)
-    while vpad % v_block != 0 and v_block > 8:
-        v_block //= 2
-    if vpad % v_block != 0:
-        raise ValueError(f"vpad={vpad} has no usable v_block ≤ requested")
+    v_block = _fit_v_block(vpad, v_block)
     if metric not in ("dot", "l2"):
         raise ValueError(metric)
     if metric == "l2":
@@ -444,6 +478,7 @@ def filtered_scan_tiled(
 
     nvb = vpad // v_block
     grid = (s, nvb)
+    kp = -(-k // 128) * 128
 
     def im_query(si, vi, sc, st):
         del vi, sc
@@ -463,7 +498,10 @@ def filtered_scan_tiled(
 
     # Mosaic tiles the last two block dims by (8, 128) unless a dim spans
     # the whole array, so every per-row operand gets a unit middle axis
-    # ([K, 1, Vpad], block (1, 1, VB)) and npass comes back as [S, QB, 1].
+    # ([K, 1, Vpad], block (1, 1, VB)), npass comes back as [S, QB, 1] and
+    # nfold as [S, 1, 1].  The running top-k lives in lane-aligned
+    # [QB, round_up(k, 128)] scratch, so its shift is a full-lane roll; the
+    # first k lanes go to the outputs on a slot's last block.
     # Attributes go in as [K, M, Vpad]: with M=10 as the minor dim the TPU
     # layout pads it to 128 lanes, a 12.8× relayout copy of the whole
     # attribute table per call.  The DNF bounds are flattened to
@@ -505,22 +543,28 @@ def filtered_scan_tiled(
             pl.BlockSpec((1, q_block, k), im_out),
             pl.BlockSpec((1, q_block, k), im_out),
             pl.BlockSpec((1, q_block, 1), im_out),
+            pl.BlockSpec((1, 1, 1), im_out),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((q_block, kp), jnp.float32),
+            pltpu.VMEM((q_block, kp), jnp.int32),
         ],
     )
-    vals, out_ids, npass = pl.pallas_call(
+    vals, out_ids, npass, nfold = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((s, q_block, k), jnp.float32),
             jax.ShapeDtypeStruct((s, q_block, k), jnp.int32),
             jax.ShapeDtypeStruct((s, q_block, 1), jnp.int32),
+            jax.ShapeDtypeStruct((s, 1, 1), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(slot_cluster.astype(jnp.int32), slot_tile.astype(jnp.int32), *operands)
-    return vals, out_ids, npass[..., 0]
+    return vals, out_ids, npass[..., 0], nfold[:, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

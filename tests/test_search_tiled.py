@@ -20,6 +20,7 @@ from repro.core import (
     match_all,
     recall_at_k,
 )
+from repro.core.engine import SearchEngine
 from repro.core.ivf import quantize_index
 from repro.core.probes import dedup_rows, plan_probe_tiles
 from repro.core.search import search_centroids, search_reference
@@ -29,6 +30,7 @@ from repro.kernels.filtered_scan import (
     filtered_scan_tiled_ref,
     search_fused_tiled,
 )
+from repro.kernels.filtered_scan.filtered_scan import fold_steps
 
 BACKENDS = ("xla", "pallas_interpret")
 
@@ -131,8 +133,8 @@ def test_tiled_kernel_matches_ref(metric):
             c["hi"], c["vectors"], c["attrs"], c["ids"],
             norms if metric == "l2" else None)
     kw = dict(metric=metric, k=7, q_block=8)
-    vals, ids, npass = filtered_scan_tiled(*args, interpret=True,
-                                           v_block=128, **kw)
+    vals, ids, npass, _ = filtered_scan_tiled(*args, interpret=True,
+                                              v_block=128, **kw)
     rvals, rids, rnpass = filtered_scan_tiled_ref(*args, **kw)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals),
                                rtol=1e-5, atol=1e-5)
@@ -151,13 +153,145 @@ def test_tiled_kernel_sq8_matches_ref():
     args = (c["slot_cluster"], c["slot_tile"], c["queries"], c["lo"],
             c["hi"], q8, c["attrs"], c["ids"], None, scale)
     kw = dict(metric="dot", k=5, q_block=8)
-    vals, ids, npass = filtered_scan_tiled(*args, interpret=True,
-                                           v_block=64, **kw)
+    vals, ids, npass, _ = filtered_scan_tiled(*args, interpret=True,
+                                              v_block=64, **kw)
     rvals, rids, rnpass = filtered_scan_tiled_ref(*args, **kw)
     np.testing.assert_allclose(np.asarray(vals), np.asarray(rvals),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
     np.testing.assert_array_equal(np.asarray(npass), np.asarray(rnpass))
+
+
+# The top-k fold inserts only block entries above a query's running kth.
+# Integer rows and queries make every score exact, so ties are common and
+# the kernel must equal the reference bit for bit, order under ties
+# included.  Lists: 0 is the case's own layout; 1 is dead; 2 has a dead
+# block and a partly dead one; 3 repeats its first block in every block,
+# so later blocks tie the running set, at the kth too.
+FOLD_QB, FOLD_VPAD, FOLD_D, FOLD_M = 8, 512, 16, 2
+FOLD_SLOTS = ((0, 0), (1, 0), (2, 1), (3, 1), (0, 1))  # (list, tile)
+FOLD_CASES = {
+    # name: (list 0 layout, metric, SQ8, k, v_block, value range, filter)
+    "ties-in-block-k100-vb128": ("random", "dot", False, 100, 128, 2, "wide"),
+    "ties-across-blocks-k100-vb256": ("repeat", "dot", False, 100, 256, 3,
+                                      "wide"),
+    "ties-at-kth-k13-vb128": ("repeat", "dot", False, 13, 128, 2, "wide"),
+    "ascending-k100-vb128": ("ascending", "dot", False, 100, 128, 2, "wide"),
+    "ascending-k100-vb256": ("ascending", "dot", False, 100, 256, 2, "wide"),
+    "ascending-k13-vb128": ("ascending", "dot", False, 13, 128, 2, "wide"),
+    "dead-blocks-k10-vb128": ("dead", "dot", False, 10, 128, 4, "wide"),
+    "fewer-than-k-k100-vb256": ("random", "dot", False, 100, 256, 4,
+                                "narrow"),
+    "l2-k13-vb128": ("random", "l2", False, 13, 128, 2, "wide"),
+    "l2-ascending-k100-vb256": ("ascending", "l2", False, 100, 256, 2,
+                                "wide"),
+    "sq8-k100-vb128": ("random", "dot", True, 100, 128, 2, "wide"),
+    "sq8-repeat-k13-vb256": ("repeat", "dot", True, 13, 256, 3, "narrow"),
+}
+
+
+def _fold_case(layout, metric, sq8, span, filt):
+    rng = np.random.default_rng(11)
+    kc, n_q = 4, 2 * FOLD_QB
+    vec = rng.integers(-span, span + 1, (kc, FOLD_VPAD, FOLD_D))
+    q = rng.integers(-span, span + 1, (n_q, FOLD_D))
+    attrs = rng.integers(-6, 7, (kc, FOLD_VPAD, FOLD_M))
+    ids = rng.permutation(kc * FOLD_VPAD).reshape(kc, FOLD_VPAD)
+    ids = np.where(rng.random((kc, FOLD_VPAD)) < 0.1, -1, ids)
+    if layout == "ascending":  # every query's scores rise along list 0
+        # dot: q₀·v₀ with q₀ > 0 and v₀ rising; l2: v₀ < 0 rising towards
+        # every q₀ > 0, so ‖q − v‖ falls
+        vec[0] = 0
+        vec[0, :, 0] = np.arange(1, FOLD_VPAD + 1) - (
+            FOLD_VPAD + 1 if metric == "l2" else 0)
+        q[:, 0] = np.abs(q[:, 0]) + 1
+        attrs[0] = 0
+        ids[0] = np.arange(FOLD_VPAD)
+    elif layout == "dead":  # list 0 dead but for its last block
+        ids[0, :-128] = -1
+    elif layout == "repeat":
+        vec[0] = np.tile(vec[0, :128], (FOLD_VPAD // 128, 1))
+        attrs[0] = np.tile(attrs[0, :128], (FOLD_VPAD // 128, 1))
+    ids[1] = -1
+    ids[2, 128:256] = -1
+    ids[2, 256:384:3] = -1
+    vec[3] = np.tile(vec[3, :128], (FOLD_VPAD // 128, 1))
+    attrs[3] = np.tile(attrs[3, :128], (FOLD_VPAD // 128, 1))
+    if filt == "wide":
+        lo = rng.integers(-6, 1, (n_q, 1, FOLD_M))
+        hi = rng.integers(0, 7, (n_q, 1, FOLD_M))
+    else:  # a few rows per list pass
+        lo = rng.integers(-6, 7, (n_q, 1, FOLD_M))
+        hi = lo + 1
+    vectors = vec.astype(np.float32)
+    norms = scales = None
+    if metric == "l2":
+        norms = jnp.asarray(np.sum(vectors ** 2, -1))
+    if sq8:
+        scales = jnp.asarray(np.full((kc, FOLD_VPAD), 0.25, np.float32))
+        vectors = vectors.astype(np.int8)
+    sc, st = (jnp.asarray(c, jnp.int32) for c in zip(*FOLD_SLOTS))
+    return (sc, st, jnp.asarray(q.astype(np.float32)),
+            jnp.asarray(lo, jnp.int16), jnp.asarray(hi, jnp.int16),
+            jnp.asarray(vectors), jnp.asarray(attrs, jnp.int16),
+            jnp.asarray(ids, jnp.int32), norms, scales)
+
+
+def _fold_passes_model(args, *, metric, k, v_block):
+    """Per slot: for each row block, the most block scores any query of the
+    tile has strictly above its running kth, at most k, summed."""
+    sc, st, q, lo, hi, vectors, attrs, ids, norms, scales = (
+        None if a is None else np.asarray(a) for a in args)
+    out = []
+    for c, t in zip(sc, st):
+        qq = q[t * FOLD_QB:(t + 1) * FOLD_QB]
+        s = (qq @ vectors[c].astype(np.float32).T).astype(np.float32)
+        if scales is not None:
+            s = s * scales[c][None]
+        if metric == "l2":
+            s = np.float32(2.0) * s - norms[c][None]
+        a = attrs[c][None]
+        ok = np.all((a >= lo[t * FOLD_QB:(t + 1) * FOLD_QB, 0][:, None])
+                    & (a <= hi[t * FOLD_QB:(t + 1) * FOLD_QB, 0][:, None]),
+                    -1) & (ids[c] >= 0)[None]
+        s = np.where(ok, s, -np.inf)
+        passes = 0
+        for b0 in range(0, FOLD_VPAD, v_block):
+            seen = np.sort(s[:, :b0], axis=1)[:, ::-1]
+            kth = (seen[:, k - 1] if b0 >= k
+                   else np.full(FOLD_QB, -np.inf))
+            above = np.sum(s[:, b0:b0 + v_block] > kth[:, None], axis=1)
+            passes += int(np.minimum(above, k).max())
+        out.append(passes)
+    return np.asarray(out)
+
+
+@pytest.mark.parametrize("case", sorted(FOLD_CASES))
+def test_tiled_fold_matches_ref(case):
+    layout, metric, sq8, k, v_block, span, filt = FOLD_CASES[case]
+    args = _fold_case(layout, metric, sq8, span, filt)
+    kw = dict(metric=metric, k=k, q_block=FOLD_QB)
+    vals, ids, npass, nfold = filtered_scan_tiled(
+        *args, interpret=True, v_block=v_block, **kw)
+    rvals, rids, rnpass = filtered_scan_tiled_ref(*args, **kw)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
+    np.testing.assert_array_equal(np.asarray(npass), np.asarray(rnpass))
+
+    nfold = np.asarray(nfold)
+    steps = FOLD_VPAD // v_block
+    np.testing.assert_array_equal(
+        nfold, _fold_passes_model(args, metric=metric, k=k, v_block=v_block))
+    assert (nfold <= steps * k).all()  # at most k passes per grid step
+    assert nfold[1] == 0  # a dead list needs no pass
+    if layout == "ascending":  # every block beats every kth: k per block
+        assert nfold[0] == nfold[4] == steps * k
+    if filt == "narrow":  # fewer rows pass than k: NEG_INF / -1 pads
+        npass = np.asarray(npass)
+        assert (npass < k).any()
+        pad = np.arange(k) >= npass[..., None]
+        assert (np.asarray(ids)[pad] == -1).all()
+        assert (np.asarray(vals)[pad] == -3.0e38).all()
 
 
 # ---------------------------------------------------------------------------
@@ -304,3 +438,39 @@ def test_serving_search_fn_uses_tiled_path(built):
     scores, ids = fn(queries, match_all(q, 6), None)
     ref = search_reference(index, queries, match_all(q, 6), k=5, n_probes=4)
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(ref.ids))
+
+
+@pytest.mark.parametrize("executor", [
+    dict(pipeline="off"), dict(pipeline="on"),
+    dict(pipeline="off", termination="exact"),
+], ids=["sync", "pipelined", "terminated"])
+def test_engine_counts_fold_passes(built, executor):
+    """Every executor that runs the kernel counts its insertion passes
+    against k per grid step; the XLA executor has no fold and counts none."""
+    index, core, attrs = built
+    q, k = 21, 10
+    queries = jnp.asarray(core[:q] + 0.01)
+    kw = dict(k=k, n_probes=4, q_block=16, v_block=128, u_cap=10,
+              **executor)
+    kernel = SearchEngine(index, backend="pallas_interpret", **kw)
+    xla = SearchEngine(index, backend="xla", **kw)
+    want = search_reference(index, queries, match_all(q, 6), k=k,
+                            n_probes=4)
+    for eng in (kernel, xla):
+        res = eng.search(queries, match_all(q, 6))
+        np.testing.assert_array_equal(np.asarray(res.ids),
+                                      np.asarray(want.ids))
+    m = kernel.metrics()
+    steps = fold_steps(int(index.vectors.shape[1]), 128)
+    slots = 2 * 10  # two query tiles × u_cap
+    if "termination" in executor:  # the scanned segments' padded slots
+        assert m["engine.fold_k_passes"] > 0
+        assert m["engine.fold_k_passes"] % (steps * k) == 0
+    else:
+        assert m["engine.fold_k_passes"] == slots * steps * k
+    assert 0 < m["engine.fold_passes"] <= m["engine.fold_k_passes"]
+    assert 'repro_engine_fold_passes ' in kernel.metrics_text()
+    mx = xla.metrics()
+    assert mx["engine.fold_passes"] == mx["engine.fold_k_passes"] == 0
+    kernel.close()
+    xla.close()

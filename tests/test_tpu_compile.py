@@ -1,10 +1,10 @@
 """Compiles the tiled filtered-scan kernel for a described TPU v5e chip.
 
 Nothing runs: the TPU compiler (Mosaic) lowers and compiles the kernel at
-the paper's widths — d=768, query tile QB=64, row block VB=256, k up to
-100 — against a chip that is described, not attached.  This catches what
-interpret mode cannot: block shapes not aligned to the (8, 128) tiling,
-ops with no Mosaic lowering, and kernels that overrun scoped VMEM.
+the paper's widths — d=768, query tile QB=64, row blocks VB=256 and 128,
+k up to 100 — against a chip that is described, not attached.  This
+catches what interpret mode cannot: block shapes not aligned to the (8, 128)
+tiling, ops with no Mosaic lowering, and kernels that overrun scoped VMEM.
 
 The topology is described inside a fixture, never at import, so every
 pytest-xdist worker collects the same tests and only the worker that runs
@@ -20,8 +20,8 @@ import pytest
 from repro.kernels.filtered_scan import filtered_scan_tiled
 
 D, M, F = 768, 10, 2  # paper widths: 768-d rows, 10 int16 attributes
-QB, VB = 64, 256
-K, VPAD = 64, 36864  # 64 lists at the paper cell's padded length
+QB = 64
+K = 64  # lists
 S = 64  # unique probe slots in one batch
 
 
@@ -48,19 +48,23 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-# (row store dtype, query dtype, metric, k): bf16 lists at two k, the SQ8
-# int8 lists (f32 queries, per-row f32 scales), and l2 with f32 norms.
+# (row store dtype, query dtype, metric, k, Vpad, VB): bf16 lists at two k,
+# the SQ8 int8 lists (f32 queries, per-row f32 scales) and l2 with f32 norms
+# at the paper cell's padded length and VB 256; and the case-study cell's
+# lists (Vpad 46,976 = 367 × 128), which take the 128-row block.
 CASES = {
-    "bf16-dot-k10": (jnp.bfloat16, jnp.bfloat16, "dot", 10),
-    "bf16-dot-k100": (jnp.bfloat16, jnp.bfloat16, "dot", 100),
-    "int8-dot-k100": (jnp.int8, jnp.float32, "dot", 100),
-    "bf16-l2-k100": (jnp.bfloat16, jnp.bfloat16, "l2", 100),
+    "bf16-dot-k10": (jnp.bfloat16, jnp.bfloat16, "dot", 10, 36864, 256),
+    "bf16-dot-k100": (jnp.bfloat16, jnp.bfloat16, "dot", 100, 36864, 256),
+    "bf16-dot-k100-vb128": (jnp.bfloat16, jnp.bfloat16, "dot", 100, 46976,
+                            128),
+    "int8-dot-k100": (jnp.int8, jnp.float32, "dot", 100, 36864, 256),
+    "bf16-l2-k100": (jnp.bfloat16, jnp.bfloat16, "l2", 100, 36864, 256),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_tiled_scan_compiles_for_v5e(one_chip, case):
-    store, qdtype, metric, k = CASES[case]
+    store, qdtype, metric, k, vpad, vb = CASES[case]
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -68,15 +72,15 @@ def test_tiled_scan_compiles_for_v5e(one_chip, case):
     args = [
         sds((S,), jnp.int32), sds((S,), jnp.int32), sds((QB, D), qdtype),
         sds((QB, F, M), jnp.int16), sds((QB, F, M), jnp.int16),
-        sds((K, VPAD, D), store), sds((K, VPAD, M), jnp.int16),
-        sds((K, VPAD), jnp.int32),
-        sds((K, VPAD), jnp.float32) if metric == "l2" else None,
-        sds((K, VPAD), jnp.float32) if store == jnp.int8 else None,
+        sds((K, vpad, D), store), sds((K, vpad, M), jnp.int16),
+        sds((K, vpad), jnp.int32),
+        sds((K, vpad), jnp.float32) if metric == "l2" else None,
+        sds((K, vpad), jnp.float32) if store == jnp.int8 else None,
     ]
 
     def scan(*a):
         return filtered_scan_tiled(*a, metric=metric, k=k, q_block=QB,
-                                   v_block=VB)
+                                   v_block=vb)
 
     compiled = jax.jit(scan).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
