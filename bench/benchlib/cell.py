@@ -1,12 +1,12 @@
 """One run of a cell: set-up, the open-loop window, and the check.
 
-Set-up makes the rows on the device from the seed, hands the program the
-benchmark's partition (``build_from_assignments``), wraps
-``make_fused_search_fn`` in a ``SearchServer`` and warms every batch shape
-the traffic reaches.  The window sends the cell's traffic open loop at the
+Set-up has the configuration's deployment (``bench/deployments/<name>.py``)
+make its data from the seed and build the program's search function on
+it, wraps that in a ``SearchServer`` and warms every batch shape the
+traffic reaches.  The window sends the cell's traffic open loop at the
 cell's fixed rate; each latency counts from the request's scheduled send
 time.  After the window, the program's state is freed and the served
-answers are compared with the plain reference (``reference.py``).
+answers are compared with the deployment's plain reference.
 """
 
 from __future__ import annotations
@@ -29,8 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchlib import reference, trace as trace_lib, traffic
-from benchlib.data import Corpus
-from benchlib.work import ScanWork
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
@@ -129,11 +127,7 @@ class System:
     """The program under test, built on one run's data."""
 
     cfg: dict
-    corpus: Corpus
-    centroids: object  # [K, D] f32, the benchmark's partition
-    counts: np.ndarray  # [K] rows per list
-    lists: np.ndarray  # [N] list of each row
-    attrs: np.ndarray  # [N, M] attributes, on the host
+    dep: object  # the deployment: the run's data, requests and reference
     fn: object = None
     server: object = None
     recorder: Optional[Recorder] = None
@@ -147,6 +141,7 @@ class System:
             self.server.stop()
         if self.fn is not None:
             self.fn.close()
+        self.dep.free()
         self.fn = self.server = self.recorder = None
         gc.collect()
 
@@ -157,89 +152,53 @@ def peak_bytes() -> int:
     return int(max(peaks, default=0))
 
 
-def build(cfg: dict, seed: int, *, on_tpu: bool, variant: str = "",
-          fault: Optional[Callable] = None) -> System:
-    """Data from the seed, then the program's index and server on it.
-    ``variant="sq8"`` serves the program's int8 lists instead: the
-    control, one precision below the configuration's bf16."""
-    from repro.core import HybridSpec
-    from repro.core.ivf import build_from_assignments, quantize_index
-    from repro.core.serving import SearchServer, make_fused_search_fn
+def build(cfg: dict, deployment, seed: int, *, on_tpu: bool,
+          variant: str = "", fault: Optional[Callable] = None) -> System:
+    """The deployment's data from the seed, then the program's search
+    function on it (``variant`` picks another build of the program, such
+    as a control) and the server around it."""
+    from repro.core.serving import SearchServer
 
     t = time.perf_counter()
-    corpus = Corpus(cfg, seed)
-    centroids = corpus.centroids()
-    core, attrs, lists = corpus.all_rows(centroids)
-    lists_h, attrs_h = np.asarray(lists), np.asarray(attrs)
-    counts = np.bincount(lists_h, minlength=cfg["n_lists"])
-    index, stats = build_from_assignments(
-        HybridSpec(cfg["dim"], cfg["n_attrs"], metric=cfg["metric"]),
-        centroids, core, attrs, lists, vpad=cfg.get("list_capacity"))
-    del core, attrs, lists
-    if variant == "sq8":
-        index = quantize_index(index)
-    elif variant:
-        raise ValueError(f"unknown variant {variant!r}")
-    jax.block_until_ready(index.vectors)
-    if stats.n_dropped:
-        raise RuntimeError(f"build dropped {stats.n_dropped} rows")
-    fn = make_fused_search_fn(index, k=cfg["top_k"], n_probes=cfg["n_probes"],
-                              q_block=cfg["batch"])
+    dep = deployment.Deployment(cfg, seed)
+    fn, server_args, sizes = dep.build(variant)
     if on_tpu and fn.engine.backend != "pallas":
         raise RuntimeError(f"engine chose backend {fn.engine.backend!r} on "
                            f"a TPU, not the compiled kernel 'pallas'")
     rec = Recorder(fn, fault)
-    server = SearchServer(rec, batch_size=cfg["batch"], dim=cfg["dim"],
-                          n_attrs=cfg["n_attrs"], n_terms=1, n_shards=1)
-    sizes = dict(rows=corpus.n_rows, dim=cfg["dim"], n_attrs=cfg["n_attrs"],
-                 n_lists=cfg["n_lists"], vpad=stats.vpad,
-                 max_list=stats.max_list_len,
-                 mean_list=round(stats.mean_list_len, 1),
-                 index_bytes=index.nbytes(), backend=fn.engine.backend,
-                 store=str(index.vectors.dtype),
-                 build_s=round(time.perf_counter() - t, 3))
-    del index
-    return System(cfg, corpus, centroids, counts, lists_h, attrs_h, fn,
-                  server, rec, sizes)
+    server = SearchServer(rec, batch_size=cfg["batch"], **server_args)
+    sizes["build_s"] = round(time.perf_counter() - t, 3)
+    return System(cfg, dep, fn, server, rec, sizes)
 
 
-def direct(system: System, q, lo, hi):
-    """One batch through the search function, padded as the server pads."""
-    from repro.core.filters import FilterSpec
-
-    cfg = system.cfg
-    b, size = len(q), cfg["batch"]
-    qq = np.zeros((size, cfg["dim"]), np.float32)
-    ll = np.zeros((size, 1, cfg["n_attrs"]), np.int16)
-    hh = np.zeros_like(ll)
-    qq[:b], ll[:b, 0], hh[:b, 0] = q, lo, hi
-    s, i = system.fn(jnp.asarray(qq), FilterSpec(lo=jnp.asarray(ll),
-                                                 hi=jnp.asarray(hh)),
-                     jnp.ones((1,), bool))
-    return np.asarray(s)[:b], np.asarray(i)[:b]
+def direct(system: System, r, rows):
+    """The requests ``rows`` of ``r`` as one batch through the search
+    function, padded as the server pads."""
+    queries, fspec = system.dep.batch(r, rows, system.cfg["batch"])
+    s, i = system.fn(queries, fspec, jnp.ones((1,), bool))
+    return np.asarray(s)[:len(rows)], np.asarray(i)[:len(rows)]
 
 
 def warm(system: System, mix: dict, seed: int) -> dict:
     """Every batch shape the traffic reaches: partial batches of the mix's
     own requests, from one query to a full batch, on two draws."""
-    cfg = system.cfg
+    size = system.cfg["batch"]
     rng = np.random.default_rng([seed, 3])
     for _ in range(2):
-        r = traffic.draw(mix, cfg["batch"], cfg["attr_cardinality"],
-                         cfg["n_topics"], rng)
-        q = system.corpus.queries(r.topics, stream=3)
+        r = system.dep.draw(mix, size, rng, stream=3)
         for b in WARM_SIZES:
-            if b <= cfg["batch"]:
-                direct(system, q[:b], r.lo[:b], r.hi[:b])
+            if b <= size:
+                direct(system, r, np.arange(b))
     return dict(system.fn.engine.stats.u_cap_hist)
 
 
-def start(cfg: dict, mix: dict, seed: int, *, on_tpu: bool,
+def start(cfg: dict, deployment, mix: dict, seed: int, *, on_tpu: bool,
           variant: str = "", fault: Optional[Callable] = None) -> System:
     """Set-up: the data and the program (``build``), every shape the
     traffic reaches warmed (``warm``), and the server started."""
     t = time.monotonic()
-    system = build(cfg, seed, on_tpu=on_tpu, variant=variant, fault=fault)
+    system = build(cfg, deployment, seed, on_tpu=on_tpu, variant=variant,
+                   fault=fault)
     t_built = time.monotonic()
     system.u_caps = warm(system, mix, seed)
     system.times = dict(build_s=t_built - t,
@@ -267,7 +226,7 @@ class Window:
     snap: Dict[str, dict]  # "start"/"end" counters at the window's edges
     compiles: dict  # compiles inside the window
     gc: dict  # the garbage collector's pauses inside the window
-    requests: object  # .queries [n, D], .lo/.hi [n, M], .cls, .topics
+    requests: traffic.Requests  # the deployment's, with its payload
     trace: Optional[trace_lib.Trace] = None
 
     @property
@@ -343,13 +302,11 @@ def open_loop(system: System, mix: dict, rate: float, seconds: float,
     for every answer (a minute past the close at most) and returns the
     record.  With ``trace`` the profiler traces the window, into a
     directory of this run's own under ``TMPDIR`` that is removed after."""
-    cfg = system.cfg
     rel = traffic.schedule(mix, rate, seconds, np.random.default_rng([seed, 1]))
-    r = traffic.draw(mix, len(rel), cfg["attr_cardinality"], cfg["n_topics"],
-                     np.random.default_rng([seed, 2]))
-    r.queries = system.corpus.queries(r.topics, stream=2)
-    queries = r.queries
-    n, k = len(rel), cfg["top_k"]
+    r = system.dep.draw(mix, len(rel), np.random.default_rng([seed, 2]),
+                        stream=2)
+    n, k = len(rel), system.cfg["top_k"]
+    submit_args = system.dep.submit_args
     sent = np.full(n, np.nan)
     futs: list = [None] * n
     server = system.server
@@ -360,7 +317,7 @@ def open_loop(system: System, mix: dict, rate: float, seconds: float,
         for i in range(n):
             sleep_until(target[i])
             sent[i] = time.monotonic()
-            futs[i] = server.submit(queries[i], (r.lo[i:i + 1], r.hi[i:i + 1]))
+            futs[i] = server.submit(*submit_args(r, i))
 
     sender = threading.Thread(target=send, daemon=True)
     snap = {}
@@ -411,10 +368,10 @@ def open_loop(system: System, mix: dict, rate: float, seconds: float,
 
 def scan_work(system: System, win: Window, device_kind: str) -> dict:
     """Least scan time of each batch begun in the traced window, from the
-    work the search needs (``work.py``) and the chip's peaks."""
+    work the search needs (the deployment's ``work``) and the chip's
+    peaks."""
     from benchlib.peaks import least_seconds
 
-    cfg = system.cfg
     if win.trace is None:
         return {}
     lo, hi = win.trace.window
@@ -422,24 +379,20 @@ def scan_work(system: System, win: Window, device_kind: str) -> dict:
               if s.name == trace_lib.BATCH and lo <= s.start < hi]
     if not traced:
         return {}
-    sw = ScanWork(system.lists, system.attrs, cfg["n_lists"], cfg["dim"],
-                  cfg["dim"] * jnp.dtype(cfg["store_dtype"]).itemsize)
     b_of = win.batch_of()
-    r = win.requests
+    size = system.cfg["batch"]
     out = {}
     for b in traced:
         rows = np.nonzero(b_of == b)[0]
         if len(rows) == 0:
             continue
-        q = np.zeros((cfg["batch"], cfg["dim"]), np.float32)
-        q[:len(rows)] = r.queries[rows]  # one program for every batch
-        probes, _ = reference.probe_lists(
-            jnp.asarray(q), system.centroids, jnp.asarray(system.counts),
-            t=cfg["n_probes"])
-        probes = np.asarray(probes)[:len(rows), :cfg["n_probes"]]
-        n_bytes, flops = sw.batch(probes, r.lo[rows], r.hi[rows],
-                                  cfg["batch"])
-        out[b] = least_seconds(device_kind, n_bytes, flops)
+        # answers are mapped to batches by time, so under a full queue a
+        # batch can take a few of its neighbour's: those count as one more
+        # batch of work, never as a batch larger than the server's
+        work = [system.dep.work(win.requests, rows[i:i + size], size)
+                for i in range(0, len(rows), size)]
+        out[b] = least_seconds(device_kind, sum(w[0] for w in work),
+                               sum(w[1] for w in work))
     return out
 
 
@@ -454,9 +407,10 @@ def view(win: Window, *, batch_size: int, device_kind: str,
         scan_least_s=scan_least_s or {}, kernel=KERNEL)
 
 
-def serve(cfg: dict, mix: dict, params: dict, seed: int, seconds: float,
-          device: dict, *, trace: bool = False, variant: str = "",
-          fault: Optional[Callable] = None, t_start: Optional[float] = None):
+def serve(cfg: dict, deployment, mix: dict, params: dict, seed: int,
+          seconds: float, device: dict, *, trace: bool = False,
+          variant: str = "", fault: Optional[Callable] = None,
+          t_start: Optional[float] = None):
     """One run of a cell: set-up, one open-loop window at the cell's rate,
     the program's state freed, then the check.  Records the device's peak
     memory in ``device``.  Returns the window's ``view`` and the verdict
@@ -466,8 +420,8 @@ def serve(cfg: dict, mix: dict, params: dict, seed: int, seconds: float,
     t_start = time.monotonic() if t_start is None else t_start
     try:
         t_init = time.monotonic()
-        system = start(cfg, mix, seed, on_tpu=on_tpu, variant=variant,
-                       fault=fault)
+        system = start(cfg, deployment, mix, seed, on_tpu=on_tpu,
+                       variant=variant, fault=fault)
         t_started = time.monotonic()
         win = open_loop(system, mix, params["rate_qps"], seconds, seed,
                         watch, trace)
@@ -500,27 +454,20 @@ def check(system: System, win: Window, limits: dict, n_check: int,
     name distinct rows in range that pass its filter; a sample drawn from
     the seed is compared with the reference, score by score and id by id.
     """
-    cfg = system.cfg
+    cfg, dep = system.cfg, system.dep
     r = win.requests
     w = np.nonzero(win.in_window)[0]
     answered = w[~np.isnan(win.done[w])]
     missing = len(w) - len(answered) + int(win.failed[w].sum())
-    faults = reference.answer_faults(win.ids[answered], r.lo[answered],
-                                     r.hi[answered], system.attrs,
-                                     system.corpus.n_rows)
+    faults = dep.answer_faults(win.ids[answered], r, answered)
     rng = np.random.default_rng([seed, 4])
     pick = np.sort(rng.choice(answered, size=min(n_check, len(answered)),
                               replace=False))
     if len(pick) == 0:
         raise RuntimeError("no request sent in the window was answered")
-    q = r.queries[pick]
     t = cfg["n_probes"]
-    probes, pv = reference.probe_lists(jnp.asarray(q), system.centroids,
-                                       jnp.asarray(system.counts), t=t)
-    probes, pv = np.asarray(probes), np.asarray(pv)
-    args = (system.corpus, system.lists, cfg["n_lists"], q, r.lo[pick],
-            r.hi[pick])
-    ref_s, ref_i, bf_i = reference.search(*args, probes[:, :t], cfg["top_k"])
+    probes, pv = dep.probe_lists(r.queries[pick], t)
+    ref_s, ref_i, bf_i = dep.search(r, pick, probes[:, :t], cfg["top_k"])
     got_s, got_i = win.scores[pick], win.ids[pick]
     tol = limits["score_gap"]
     cmp = reference.compare(got_s, got_i, ref_s, ref_i, tol)
@@ -534,9 +481,7 @@ def check(system: System, win: Window, limits: dict, n_check: int,
     bad = set(cmp["bad"])
     if redo:
         alt = probes[redo][:, list(range(t - 1)) + [t]]
-        a_s, a_i, _ = reference.search(
-            system.corpus, system.lists, cfg["n_lists"], q[redo],
-            r.lo[pick][redo], r.hi[pick][redo], alt, cfg["top_k"])
+        a_s, a_i, _ = dep.search(r, pick[redo], alt, cfg["top_k"])
         c2 = reference.compare(got_s[redo], got_i[redo], a_s, a_i, tol)
         for j, row in enumerate(redo):
             if j not in c2["bad"]:

@@ -1,13 +1,17 @@
 """Finds everything a cell needs by the names in ``BENCHMARK.json``.
 
 - ``bench/configs/<config>.json``: the configuration's sizes and source;
+- ``bench/deployments/<deployment>.py``: what the configuration's
+  ``"deployment"`` key names: its data, the program built on it, its
+  requests, its reference and its work count (``deployments/topics.py``
+  lists what a deployment holds);
 - ``bench/traffic/<traffic>.json``: the traffic mix's parameters;
 - ``bench/cells/<workload>.json``: the cell's offered rate and its checks;
 - ``bench/metrics/<metric>.py``: one reader per metric, ``read(run)``;
   ``<base>.<part>`` falls back to ``<base>.py``.
 
-Adding a configuration, a mix, a cell or a metric adds files; no file that
-exists changes.
+Adding a deployment, a configuration, a mix, a cell or a metric adds files;
+no file that exists changes.
 """
 
 from __future__ import annotations
@@ -55,6 +59,15 @@ class Bench:
                 if workload in m.get("workloads", [workload])
                 and m["moves"] in reported]
 
+    def deployment(self, cfg: dict):
+        """The module ``bench/deployments/<cfg["deployment"]>.py``."""
+        name = cfg["deployment"]
+        path = self.dir / "deployments" / f"{name}.py"
+        if not path.is_file():
+            raise FileNotFoundError(f"configuration {cfg.get('name')!r} "
+                                    f"names deployment {name!r}: no {path}")
+        return _load(f"bench_deployment_{name}", path)
+
     def reader(self, metric: str):
         """The ``read(run)`` function of ``bench/metrics/<metric>.py``.  A
         quantity split by the end-to-end metric it moves, ``<base>.<part>``
@@ -64,8 +77,11 @@ class Bench:
         base = self.dir / "metrics" / f"{metric.split('.', 1)[0]}.py"
         if not path.exists() and base.exists():
             path = base
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(f"bench_metric_{metric.replace('.', '_')}", path).read
+
+
+def _load(name: str, path: pathlib.Path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

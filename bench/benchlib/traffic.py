@@ -11,11 +11,13 @@ A mix file (``bench/traffic/<name>.json``) holds only parameters:
 - ``topics``: ``{"dist": "uniform"}`` or ``{"dist": "zipf", "s": 1.0}``
   over the configuration's topic centres, in a rank order drawn from the
   seed.
-- ``classes``: filter classes, each with its ``share`` of the requests
-  (exact counts, in seeded order) and its ``predicates``, a conjunction of
-  ``{"attr", "op", ...}`` with ``op`` one of ``eq`` (``value``, or a value
-  drawn uniformly below the attribute's cardinality), ``le``, ``ge``, and
-  ``window`` (``width`` consecutive values at a random start).
+- ``classes``: filter classes, each with its ``name``, its ``share`` of the
+  requests (exact counts, in seeded order) and its filter, in the
+  vocabulary of the deployment that serves the mix
+  (``bench/deployments/<name>.py``).
+
+The deployment draws a mix's requests with ``classes`` and ``topics`` and
+its own filters in between, and returns them as ``Requests``.
 """
 
 from __future__ import annotations
@@ -25,37 +27,23 @@ from typing import List
 
 import numpy as np
 
-ATTR_MIN, ATTR_MAX = -32768, 32767  # the int16 attribute range
-
 
 @dataclasses.dataclass
 class Requests:
-    topics: np.ndarray  # [n] topic centre of each query
+    """What every deployment's requests carry; a deployment adds its own
+    filter payload."""
+
+    queries: np.ndarray  # [n, D] f32 query vectors
     cls: np.ndarray  # [n] index into class_names
-    lo: np.ndarray  # [n, M] int16 conjunctive bounds
-    hi: np.ndarray  # [n, M] int16
     class_names: List[str]
 
 
-def _bounds(preds, card, rng):
-    m = len(card)
-    lo = np.full(m, ATTR_MIN, np.int64)
-    hi = np.full(m, ATTR_MAX, np.int64)
-    for p in preds:
-        a, op = p["attr"], p["op"]
-        if op == "eq":
-            v = p["value"] if "value" in p else int(rng.integers(0, card[a]))
-            lo[a], hi[a] = max(lo[a], v), min(hi[a], v)
-        elif op == "le":
-            hi[a] = min(hi[a], p["value"])
-        elif op == "ge":
-            lo[a] = max(lo[a], p["value"])
-        elif op == "window":
-            s = int(rng.integers(0, card[a] - p["width"]))
-            lo[a], hi[a] = max(lo[a], s), min(hi[a], s + p["width"] - 1)
-        else:
-            raise ValueError(f"unknown predicate op {op!r}")
-    return lo.astype(np.int16), hi.astype(np.int16)
+def classes(mix: dict, n: int, rng) -> np.ndarray:
+    """[n] class of each request: the mix's shares as exact counts, in
+    seeded order."""
+    counts = [int(round(c["share"] * n)) for c in mix["classes"]]
+    counts[-1] = n - sum(counts[:-1])
+    return rng.permutation(np.repeat(np.arange(len(counts)), counts))
 
 
 def topic_weights(mix: dict, n_topics: int, rng) -> np.ndarray:
@@ -69,19 +57,9 @@ def topic_weights(mix: dict, n_topics: int, rng) -> np.ndarray:
     raise ValueError(f"unknown topic distribution {t['dist']!r}")
 
 
-def draw(mix: dict, n: int, card, n_topics: int, rng) -> Requests:
-    """``n`` requests of the mix: classes in exact shares, seeded order."""
-    classes = mix["classes"]
-    counts = [int(round(c["share"] * n)) for c in classes]
-    counts[-1] = n - sum(counts[:-1])
-    cls = rng.permutation(np.repeat(np.arange(len(classes)), counts))
-    lo = np.empty((n, len(card)), np.int16)
-    hi = np.empty((n, len(card)), np.int16)
-    for i, c in enumerate(cls):
-        lo[i], hi[i] = _bounds(classes[c]["predicates"], card, rng)
-    weights = topic_weights(mix, n_topics, rng)
-    topics = rng.choice(n_topics, size=n, p=weights)
-    return Requests(topics, cls, lo, hi, [c["name"] for c in classes])
+def topics(mix: dict, n: int, n_topics: int, rng) -> np.ndarray:
+    """[n] topic centre of each request, under the mix's distribution."""
+    return rng.choice(n_topics, size=n, p=topic_weights(mix, n_topics, rng))
 
 
 def schedule(mix: dict, rate: float, seconds: float, rng) -> np.ndarray:

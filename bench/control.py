@@ -7,10 +7,11 @@ For each of ``--seeds`` it runs the cell as a benchmark run does (set-up,
 one window of the cell's traffic at the cell's rate, the check) and prints
 the numbers the check compares (``score_gap``, ``id_mismatch``,
 ``answer_faults``, ``unanswered``).  For each of ``--control-seeds`` it
-does the same with the control: the program's own int8 (SQ8) lists, one
-precision below the configuration's bf16, compared with the same bf16
-reference.  All in one process, so the programs compile once.  The
-benchmark's runs never run the control.
+does the same with the control, the build of the program that the
+deployment names (``CONTROL``; the ``topics`` deployment's is the
+program's own int8 (SQ8) lists, one precision below the configuration's
+bf16), compared with the same reference.  All in one process, so the
+programs compile once.  The benchmark's runs never run the control.
 """
 
 from __future__ import annotations
@@ -32,8 +33,8 @@ def readings(bench, workload: str, seed: int, seconds: float, variant: str,
     if rehearsal:
         cfg = {**cfg, **cfg["rehearsal"]}
         params = {**params, **params["rehearsal"]}
-    _, verdict = C.serve(cfg, mix, params, seed, seconds, dict(device),
-                         variant=variant, fault=fault)
+    _, verdict = C.serve(cfg, bench.deployment(cfg), mix, params, seed,
+                         seconds, dict(device), variant=variant, fault=fault)
     return dict(seed=seed, variant=variant or "program",
                 correct=verdict["correct"],
                 values={k: v["value"] for k, v in verdict["checks"].items()},
@@ -52,14 +53,15 @@ def main() -> int:
     from benchlib.spec import Bench
 
     bench = Bench()
-    device = device_info(bench.workload(args.workload)["chips"],
-                         args.cpu_rehearsal)
+    cell = bench.workload(args.workload)
+    device = device_info(cell["chips"], args.cpu_rehearsal)
     if device is None:
         return 2
     if not args.cpu_rehearsal:
         use_compile_cache()
     runs = [(int(s), "") for s in args.seeds.split(",") if s]
-    runs += [(int(s), "sq8") for s in args.control_seeds.split(",") if s]
+    ctl = bench.deployment(bench.config(cell["config"])).CONTROL
+    runs += [(int(s), ctl) for s in args.control_seeds.split(",") if s]
     for seed, variant in runs:
         print(json.dumps(readings(bench, args.workload, seed, args.seconds,
                                   variant, device, args.cpu_rehearsal)),

@@ -2,13 +2,14 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell, its configuration, its traffic mix and its metrics are found by
-name from ``BENCHMARK.json`` (see ``benchlib/spec.py``).  With ``--trace 0``
-the result carries the cell's end-to-end metrics; with ``--trace 1`` the
-window is traced and the result carries its per-layer metrics.  The run
-fails, and prints no result, without a TPU or with fewer chips than the
-cell asks for.  ``--cpu-rehearsal`` runs the same flow on the CPU at the
-configuration's rehearsal size; the benchmark's command never passes it.
+The cell, its configuration with its deployment, its traffic mix and its
+metrics are found by name from ``BENCHMARK.json`` (see ``benchlib/spec.py``).
+With ``--trace 0`` the result carries the cell's end-to-end metrics; with
+``--trace 1`` the window is traced and the result carries its per-layer
+metrics.  The run fails, and prints no result, without a TPU or with fewer
+chips than the cell asks for.  ``--cpu-rehearsal`` runs the same flow on the
+CPU at the configuration's rehearsal size; the benchmark's command never
+passes it.
 """
 
 from __future__ import annotations
@@ -99,9 +100,9 @@ def run(bench, cell, args, device, *, fault=None, t_start=T_START) -> dict:
     if args.cpu_rehearsal:
         cfg = {**cfg, **cfg["rehearsal"]}
         params = {**params, **params["rehearsal"]}
-    r, verdict = C.serve(cfg, mix, params, args.seed, args.seconds, device,
-                         trace=bool(args.trace), fault=fault,
-                         t_start=t_start)
+    r, verdict = C.serve(cfg, bench.deployment(cfg), mix, params, args.seed,
+                         args.seconds, device, trace=bool(args.trace),
+                         fault=fault, t_start=t_start)
     win = r.window
     metrics = {}
     for m in bench.metrics(cell["name"], bool(args.trace)):

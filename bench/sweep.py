@@ -50,7 +50,7 @@ def main() -> int:
     watch = C.Watch()
     system = None
     try:
-        system = C.start(cfg, mix, args.seed,
+        system = C.start(cfg, bench.deployment(cfg), mix, args.seed,
                          on_tpu=device["platform"] == "tpu")
         for i, rate in enumerate(float(r) for r in args.rates.split(",")):
             win = C.open_loop(system, mix, rate, args.seconds, args.seed + i,

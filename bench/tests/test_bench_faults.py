@@ -54,3 +54,15 @@ def test_fault_is_not_correct(capsys, fault):
     assert rc == 0
     assert res["correct"] is False
     assert any(c["value"] > c["limit"] for c in res["checks"].values())
+
+
+@pytest.mark.parametrize("fault", [answer_altered, half_batch_left_out,
+                                   answers_misrouted],
+                         ids=lambda f: f.__name__)
+def test_fault_in_the_uniform_throughput_cell_is_not_correct(capsys, fault):
+    argv = ["--workload", "k1414.uniform.over"] + ARGS[2:]
+    rc = run.main(argv, fault=fault)
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0
+    assert res["correct"] is False
+    assert any(c["value"] > c["limit"] for c in res["checks"].values())

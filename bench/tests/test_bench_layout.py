@@ -1,5 +1,5 @@
-"""BENCHMARK.json keeps to its contract, and a new configuration, traffic
-mix, cell or metric needs only new files."""
+"""BENCHMARK.json keeps to its contract, and a new deployment,
+configuration, traffic mix, cell or metric needs only new files."""
 
 import hashlib
 import json
@@ -7,6 +7,10 @@ import pathlib
 import re
 import shutil
 import sys
+import tempfile
+
+import numpy as np
+import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
@@ -37,6 +41,8 @@ def test_benchmark_json_keeps_to_its_contract():
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert set(c["reduced"]) == set(cfg["reduced"])
+        assert (ROOT / "bench/deployments"
+                / f"{cfg['deployment']}.py").is_file()
     for w in s["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
@@ -118,3 +124,85 @@ def test_split_metric_is_read_by_its_base_reader():
     for name in split:
         base = name.split(".", 1)[0]
         assert bench.reader(name).__code__.co_filename.endswith(f"/{base}.py")
+
+
+def _copy_bench(tmp_path):
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return tmp_path / "bench"
+
+
+def _id_altered(queries, fspec, out):
+    s, i = (np.array(x) for x in out)
+    i[0, 0] = i[0, 0] + 1 if i[0, 0] >= 0 else i[0, 0]
+    return s, i
+
+
+def test_second_deployment_needs_only_new_files(tmp_path, monkeypatch):
+    """A deployment that differs from ``topics`` in metric (l2), tier (disk,
+    under a resident budget below the index's bytes) and filters (two DNF
+    terms) comes as a module, a config, a mix and a cell, all new."""
+    tmp = tmp_path / "tmp"
+    tmp.mkdir()
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp))  # index and trace
+    b = _copy_bench(tmp_path)
+    before = _digest(b)
+    shutil.copy(pathlib.Path(__file__).with_name("deployment_dnf_l2_disk.py"),
+                b / "deployments/dnf_l2_disk.py")
+    cfg = json.loads((b / "configs/case-study-k1414.json").read_text())
+    cfg.update(name="dnf-l2-disk", deployment="dnf_l2_disk", tier="disk",
+               metric="l2")
+    (b / "configs/dnf-l2-disk.json").write_text(json.dumps(cfg))
+    mix = json.loads((b / "traffic/uniform.json").read_text())
+    eq = lambda a: {"attr": a, "op": "eq"}  # noqa: E731
+    window = lambda a, w: {"attr": a, "op": "window", "width": w}  # noqa
+    mix["classes"] = [
+        {"name": "unfiltered", "share": 0.25, "terms": [[]]},
+        {"name": "one-of-two", "share": 0.25, "terms": [[eq(1)], [eq(1)]]},
+        {"name": "narrow-or-narrow", "share": 0.5,
+         "terms": [[eq(1), window(0, 1000)],
+                   [{"attr": 2, "op": "eq", "value": 0}, window(3, 100)]]}]
+    (b / "traffic/dnf2.json").write_text(json.dumps(mix))
+    (b / "cells/l2disk.dnf2.json").write_text(
+        (b / "cells/k1414.hot.over.json").read_text())
+    s = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    s["configs"].append(dict(s["configs"][1], name="dnf-l2-disk",
+                             file="bench/configs/dnf-l2-disk.json"))
+    s["workloads"].append(dict(name="l2disk.dnf2", config="dnf-l2-disk",
+                               traffic="dnf2", chips=1, why="test"))
+    for m in s["end_to_end"] + s["per_layer"]:
+        if m["name"] in ("qps", "batch_fill.tput"):
+            m["workloads"].append("l2disk.dnf2")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(s))
+    after = _digest(b)
+    assert all(after[f] == h for f, h in before.items())  # nothing edited
+
+    bench = Bench(root=tmp_path, bench=b)
+    cell = bench.workload("l2disk.dnf2")
+    argv = ["--workload", "l2disk.dnf2", "--seed", "6442450951",
+            "--seconds", "1", "--cpu-rehearsal", "--trace"]
+    res = run.run(bench, cell, run.parse_args(argv + ["1"]),
+                  run.device_info(1, True))
+    assert res["correct"] is True, res["checks"]
+    assert set(res["metrics"]) == {"batch_fill.tput"}
+    bad = run.run(bench, cell, run.parse_args(argv + ["0"]),
+                  run.device_info(1, True), fault=_id_altered)
+    assert bad["correct"] is False
+    assert bad["checks"]["id_mismatch"]["value"] + bad["checks"][
+        "answer_faults"]["value"] > 0
+    assert not list(tmp.iterdir())  # the index and the trace are removed
+
+
+@pytest.mark.parametrize("key,value", [("tier", "disk"), ("metric", "l2"),
+                                       ("store_dtype", "int8")])
+def test_topics_refuses_what_it_does_not_run(key, value):
+    bench = Bench()
+    cfg = bench.config("case-study-k64")
+    with pytest.raises(ValueError, match=key):
+        bench.deployment(cfg).Deployment({**cfg, key: value}, 1)
+
+
+def test_missing_deployment_is_an_error():
+    with pytest.raises(FileNotFoundError, match="no-such"):
+        Bench().deployment({"name": "x", "deployment": "no-such"})

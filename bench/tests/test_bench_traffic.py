@@ -1,5 +1,5 @@
 """The traffic generator: exact counts, exact class shares and seeded
-topics."""
+topics; the ``topics`` deployment's filters drawn from a mix."""
 
 import json
 import pathlib
@@ -10,10 +10,17 @@ import numpy as np
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 
 from benchlib import traffic  # noqa: E402
+from benchlib.spec import Bench  # noqa: E402
 
 MIX = json.loads((pathlib.Path(__file__).resolve().parents[1]
                   / "traffic/uniform.json").read_text())
-CARD = [10000, 10, 2] + [1000] * 7
+
+
+def topics_deployment():
+    bench = Bench()
+    cfg = bench.config("case-study-k64")
+    dep = bench.deployment(cfg)
+    return dep, dep.Deployment({**cfg, **cfg["rehearsal"]}, 3)
 
 
 def test_window_holds_an_exact_count():
@@ -25,10 +32,12 @@ def test_window_holds_an_exact_count():
 
 
 def test_classes_in_exact_shares_and_filters_in_range():
-    r = traffic.draw(MIX, 400, CARD, 1024, np.random.default_rng(3))
+    mod, dep = topics_deployment()
+    r = dep.draw(MIX, 400, np.random.default_rng(3), stream=2)
     assert np.bincount(r.cls).tolist() == [100, 100, 100, 100]
+    assert r.queries.shape == (400, 768)
     unfiltered = r.cls == r.class_names.index("unfiltered")
-    assert (r.lo[unfiltered] == traffic.ATTR_MIN).all()
+    assert (r.lo[unfiltered] == mod.ATTR_MIN).all()
     narrow = r.cls == r.class_names.index("sel~0.5%")
     assert (r.hi[narrow, 0] - r.lo[narrow, 0] == 999).all()
     assert (r.lo[narrow, 2] == 0).all() and (r.hi[narrow, 2] == 0).all()
@@ -46,10 +55,10 @@ def test_zipf_topics_in_a_seeded_rank_order():
 def test_hot_mix_concentrates_on_few_topics():
     hot = json.loads((pathlib.Path(__file__).resolve().parents[1]
                       / "traffic/hot-zipf1.json").read_text())
-    r = traffic.draw(hot, 20000, CARD, 1024, np.random.default_rng(4))
-    share = np.bincount(r.topics, minlength=1024) / 20000
+    t = traffic.topics(hot, 20000, 1024, np.random.default_rng(4))
+    share = np.bincount(t, minlength=1024) / 20000
     top = 1.0 / np.sum(1.0 / np.arange(1, 1025))  # Zipf(1)'s first rank
     assert abs(share.max() - top) < 0.01
     assert (share > 0).sum() < 1024  # the tail is thin
-    u = traffic.draw(MIX, 20000, CARD, 1024, np.random.default_rng(4))
-    assert np.bincount(u.topics, minlength=1024).max() / 20000 < 0.003
+    u = traffic.topics(MIX, 20000, 1024, np.random.default_rng(4))
+    assert np.bincount(u, minlength=1024).max() / 20000 < 0.003

@@ -55,3 +55,29 @@ def test_unknown_device_kind_is_refused():
         peaks.least_seconds("TPU v9 imaginary", 1.0, 1.0)
     with pytest.raises(KeyError):
         peaks.peaks("cpu")
+
+
+def test_batch_with_more_answers_than_its_size_counts_in_parts():
+    """Answers map to batches by time, so a batch of a full queue can hold
+    more rows than the server's batch size: its work is counted in
+    batch-sized parts, each padded to one program's shape."""
+    import types
+
+    from benchlib import cell, trace
+
+    calls = []
+
+    def work(requests, rows, size):
+        calls.append((list(rows), size))
+        return 819e9 * len(rows), 0.0  # one second per row at v5e's HBM
+
+    span = types.SimpleNamespace(name=trace.BATCH, start=5.0,
+                                 stats={"batch": 0})
+    win = types.SimpleNamespace(
+        trace=types.SimpleNamespace(window=(0.0, 10.0), spans=[span]),
+        batch_of=lambda: np.array([0, 0, 0, 0, 0, -1]), requests=None)
+    system = types.SimpleNamespace(cfg={"batch": 2},
+                                   dep=types.SimpleNamespace(work=work))
+    least = cell.scan_work(system, win, "TPU v5 lite")
+    assert calls == [([0, 1], 2), ([2, 3], 2), ([4], 2)]
+    assert least == {0: 5.0}
